@@ -45,7 +45,7 @@ def bench_fleet(n_replicas: int, n_days: int = 1, mesh=None) -> tuple[float, Fle
     env = FleetAdapter(fleet)
     steps = fleet.config.episode_steps * n_days
 
-    with sharding.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+    with jax.sharding.set_mesh(mesh) if mesh is not None else contextlib.nullcontext():
         params = env.default_params
         if mesh is not None:
             params = env_sharding.place_env_batch(params, mesh)

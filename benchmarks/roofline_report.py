@@ -11,7 +11,7 @@ work) three ways:
   pallas_interpret  — the Pallas slab kernel in interpret mode (the only
                       way to exercise the kernel's lowering on CPU; its
                       absolute time is an emulation cost, not a perf claim
-                      — on TPU/GPU the same kernel runs compiled).
+                      — on TPU the same kernel runs compiled).
 
 Persisted by ``benchmarks.run`` as ``BENCH_roofline.json`` with
 ``fused_ref_vs_staged_frac`` in the summary; CI's bench-smoke job runs it.
@@ -161,7 +161,7 @@ def run(quick: bool = True):
         (
             "kernel_pallas_interpret",
             per_step["pallas_interpret"],
-            "interpret-mode emulation cost (compiled kernel needs TPU/GPU)",
+            "interpret-mode emulation cost (compiled kernel needs a TPU)",
         )
     )
     LAST_SUMMARY = {

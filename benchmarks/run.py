@@ -86,6 +86,10 @@ def main():
     unknown = [n for n in names if n not in MODULES]
     if unknown:
         ap.error(f"unknown benchmark(s) {unknown}; choose from {list(MODULES)}")
+    from repro.utils import use_accurate_transcendentals, use_compile_cache
+
+    use_accurate_transcendentals()
+    use_compile_cache()
     writer = None
     if args.metrics_out:
         from repro.obs import MetricsWriter

@@ -23,7 +23,7 @@ HLO proof holds) plus ``wrapper_overhead_noise_residual_frac``.
 And the fused-step row (ISSUE 10): the identical wrapped rollout with
 ``EnvConfig.fused_step`` routing the pole physics through
 ``kernels/chargax_step`` — persisted as ``fused_vs_staged_frac`` with the
-resolved backend (``fused_impl``: pallas on TPU/GPU, ref on CPU).
+resolved backend (``fused_impl``: pallas on TPU, ref elsewhere).
 
 And the real-data row: a ``REAL_PACK`` scenario (ingested ENTSO-E prices +
 PVGIS solar) swapped into the same compiled rollout as the synthetic
@@ -176,7 +176,7 @@ def bench_fused_vs_staged(
 
     The fused path is ``VmapWrapper(...).with_fused_step(True)`` — the exact
     hot-path routing ``rl_train --fused`` uses — against the staged default.
-    The resolved backend (``pallas`` on TPU/GPU, ``ref`` on CPU, or whatever
+    The resolved backend (``pallas`` on TPU, ``ref`` elsewhere, or whatever
     ``CHARGAX_FUSED_IMPL`` forces) is returned so the persisted row says
     what was actually measured.  Interleaved timing, min per path.
     """

@@ -8,18 +8,6 @@ from __future__ import annotations
 
 import re
 
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every JAX version.
-
-    Newer JAX returns a dict; 0.4.x returns a one-element list of dicts (one
-    per partitioned program); either may be empty/None.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
     "s64": 8, "u64": 8, "s32": 4, "u32": 4, "s16": 2, "u16": 2,

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.analysis.hlo import collective_stats, cost_analysis_dict, materialized_bytes
+from repro.analysis.hlo import collective_stats, materialized_bytes
 from repro.configs.registry import build_model, get_config
 from repro.models.config import SHAPES, ModelConfig, ShapeConfig
 
@@ -83,7 +83,7 @@ def _compile_probe(cfg: ModelConfig, shape: ShapeConfig, mesh, microbatches: int
 
     model = build_model(cfg)
     rep = NamedSharding(mesh, P())
-    ctx = shd.set_mesh(mesh)
+    ctx = jax.sharding.set_mesh(mesh)
     ctx.__enter__()
     key = jax.random.key(0)
     params_abs = jax.eval_shape(model.init, key)
@@ -157,7 +157,7 @@ def _compile_probe(cfg: ModelConfig, shape: ShapeConfig, mesh, microbatches: int
         )
 
     ctx.__exit__(None, None, None)
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     text = compiled.as_text()
     coll = collective_stats(text)
     mem = compiled.memory_analysis()

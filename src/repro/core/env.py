@@ -58,7 +58,7 @@ class EnvConfig:
     pad_evse: int = 0
     pad_nodes: int = 0
     # hot path: route request/allocate/deliver through the fused step kernel
-    # (kernels/chargax_step) — Pallas on TPU/GPU, bit-exact jnp ref on CPU;
+    # (kernels/chargax_step) — Pallas on TPU, bit-exact jnp ref elsewhere;
     # see docs/kernels.md.  Off by default: flag-off params and HLO are
     # identical to builds that predate the flag.
     fused_step: bool = False

@@ -17,7 +17,7 @@ an *unpadded* run exactly on discrete fields / to last-ulp float tolerance
 on continuous ones (different compiled programs may round the Eq. 5 load
 reduction differently; see ``tests/core/test_fleet.py``).
 
-When a mesh is active (``repro.distributed.sharding.set_mesh``) the station
+When a mesh is active (``jax.sharding.set_mesh``) the station
 axis of ``reset``/``step`` outputs is constrained onto the mesh's data axes
 (``repro.distributed.env_sharding``), so a fleet rollout shards across
 devices with zero changes at the call site; without a mesh the constraint is

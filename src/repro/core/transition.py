@@ -55,6 +55,12 @@ BIG = 1e30
 # bitwise no-op (x * 1.0 is exact in IEEE-754).
 GRID_CAP_UNLIMITED = 1e9
 
+# A charge-sensitive car leaves once at most this much of its request remains
+# [kWh].  A step whose current reached the request bound zeroes the request
+# outright (`pole_integrate`), so the threshold only decides requests that
+# decay under the charge curve.
+CHARGED_KWH = 1e-6
+
 
 # ---------------------------------------------------------------------------
 # Charging curve (Appendix A: piece-wise linear; discharge = vertical flip
@@ -76,6 +82,13 @@ def discharge_rate(soc: jnp.ndarray, rbar: jnp.ndarray, tau: jnp.ndarray) -> jnp
 # live in path_eff), eta_b for the battery (charging stores eta*E,
 # discharging drains E/eta).
 # ---------------------------------------------------------------------------
+def request_amps(
+    e_remain: jnp.ndarray, voltage: jnp.ndarray, dt_hours: float
+) -> jnp.ndarray:
+    """Current [A] that delivers the remaining request ``e_remain`` in one step."""
+    return e_remain * 1000.0 / jnp.maximum(voltage * dt_hours, 1e-9)
+
+
 def pole_bounds(
     soc: jnp.ndarray,
     e_remain: jnp.ndarray,
@@ -95,7 +108,7 @@ def pole_bounds(
     """
     rhat_chg = charge_rate(soc, rbar, tau)
     rhat_dis = discharge_rate(soc, rbar, tau)
-    max_chg_amp_req = e_remain * 1000.0 / jnp.maximum(voltage * dt_hours, 1e-9)
+    max_chg_amp_req = request_amps(e_remain, voltage, dt_hours)
     max_chg_amp_soc = (
         (1.0 - soc) * cap * 1000.0 / jnp.maximum(voltage * dt_hours * eff, 1e-9)
     )
@@ -136,12 +149,20 @@ def pole_integrate(
     past the pack headroom ``(1 - SoC') * cap`` — an uncapped request would
     be unfillable energy poisoning the missing_kwh satisfaction penalty.
     Poles carrying the ``BIG`` request sentinel (battery) keep it.
+
+    A current that reached the request bound of :func:`pole_bounds` delivered
+    the whole request, which is then zeroed: ``e_remain - e`` would leave a
+    residual of a few rounding ulps, and the charged-departure test would
+    hinge on the last bit of a division, which differs between backends.
     """
     e = voltage * current * dt_hours / 1000.0  # kWh, pole-side
     soc_delta = jnp.where(e >= 0, e * eff, e / eff)
     soc_new = jnp.clip(soc + soc_delta / jnp.maximum(cap, 1e-6), 0.0, 1.0)
     headroom = jnp.where(e_remain >= 0.5 * BIG, BIG, (1.0 - soc_new) * cap)
-    e_remain_new = jnp.minimum(jnp.maximum(e_remain - e, 0.0), headroom)
+    request_met = current >= request_amps(e_remain, voltage, dt_hours)
+    e_remain_new = jnp.where(
+        request_met, 0.0, jnp.minimum(jnp.maximum(e_remain - e, 0.0), headroom)
+    )
     rhat_new = charge_rate(soc_new, rbar, tau) * occupied
     return e, soc_new, e_remain_new, rhat_new
 
@@ -245,7 +266,11 @@ def constraint_scale(
 
     Returns (per-leaf scale in (0, 1], max pre-rescale node excess in amps).
     """
-    load = member @ jnp.abs(currents)  # (n_nodes,)
+    # HIGHEST: a default-precision f32 dot may run in bf16 passes on TPU,
+    # which would shift node loads by ~0.1% and curtail different currents
+    load = jnp.matmul(
+        member, jnp.abs(currents), precision=jax.lax.Precision.HIGHEST
+    )  # (n_nodes,)
     s_node = jnp.minimum(1.0, node_budget / jnp.maximum(load, 1e-9))
     excess = jnp.max(jnp.maximum(load - node_budget, 0.0))
     # min over ancestors; a leaf with no constrained ancestor is unscaled
@@ -483,7 +508,7 @@ class DepartResult(NamedTuple):
 def depart_cars(state: EnvState) -> DepartResult:
     occ = state.occupied > 0.5
     leave_time = occ & (state.user_type < 0.5) & (state.t_remain <= 0)
-    leave_charge = occ & (state.user_type >= 0.5) & (state.e_remain <= 1e-6)
+    leave_charge = occ & (state.user_type >= 0.5) & (state.e_remain <= CHARGED_KWH)
     leaving = leave_time | leave_charge
 
     missing = jnp.sum(jnp.where(leave_time, jnp.maximum(state.e_remain, 0.0), 0.0))

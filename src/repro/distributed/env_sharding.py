@@ -11,7 +11,7 @@ CPU tests.
 Two flavours:
 
 * **ambient** — :func:`constrain_env_batch` annotates the leading axis of
-  every leaf against the mesh installed by ``sharding.set_mesh`` and is a
+  every leaf against the mesh installed by ``jax.sharding.set_mesh`` and is a
   no-op when none is active.  Env code (``FleetEnv``, ``make_train``) calls
   it unconditionally.
 * **explicit** — :func:`make_shard_envs` / :func:`place_env_batch` build

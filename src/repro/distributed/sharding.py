@@ -18,32 +18,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-# ---------------------------------------------------------------------------
-# JAX version compat: the ambient-mesh API moved across releases.
-#   jax >= 0.5: jax.sharding.set_mesh / jax.sharding.get_abstract_mesh
-#   jax  < 0.5: `with mesh:` sets a thread-local physical mesh readable via
-#               jax.interpreters.pxla.thread_resources
-# ``set_mesh``/``get_abstract_mesh`` below present the new-style interface on
-# both; all repo code goes through them instead of jax.sharding directly.
-# ---------------------------------------------------------------------------
-def set_mesh(mesh: Mesh):
-    """Return a context manager making ``mesh`` the ambient mesh."""
-    new = getattr(jax.sharding, "set_mesh", None)
-    if new is not None:
-        return new(mesh)
-    return mesh  # jax<0.5: Mesh is itself a context manager
-
-
-def get_abstract_mesh():
-    """The ambient mesh (empty mesh when none is active), any JAX version."""
-    new = getattr(jax.sharding, "get_abstract_mesh", None)
-    if new is not None:
-        return new()
-    from jax.interpreters import pxla
-
-    return pxla.thread_resources.env.physical_mesh
-
-
 def data_axes(mesh: Mesh) -> tuple[str, ...]:
     return tuple(a for a in mesh.axis_names if a in ("pod", "data"))
 
@@ -272,7 +246,8 @@ DP = "__data_axes__"  # sentinel: expands to whichever of (pod, data) exist
 
 
 def constrain(x, *entries):
-    """``with_sharding_constraint`` against the ambient mesh (``set_mesh``).
+    """``with_sharding_constraint`` against the ambient mesh
+    (``jax.sharding.set_mesh``).
 
     No-op when no mesh is active (single-device tests) or when an entry does
     not divide its dim.  Entries: axis name, tuple of names, the DP sentinel
@@ -280,8 +255,8 @@ def constrain(x, *entries):
     by an earlier entry are dropped (keeps 'fsdp' pins valid).  Model code
     can therefore annotate unconditionally.
     """
-    mesh = get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     names = set(mesh.axis_names)
     strategy = get_strategy()

@@ -1,7 +1,7 @@
 """Jit'd wrapper for the fused Chargax station step.
 
 Builds padded pole slabs from core env structures, dispatches to the Pallas
-kernel (TPU/GPU) or the jnp reference (CPU / other backends), and unpacks
+kernel (TPU) or the jnp reference (CPU / other backends), and unpacks
 results back into env-shaped pieces.  The battery is pole index ``n_evse``
 (the paper's (N+1)-th pole).
 
@@ -14,7 +14,7 @@ Two granularities are exposed:
   through when ``EnvConfig.fused_step`` is on.  On CPU it runs
   :func:`fused_request` (bit-identical to the staged ``apply_actions`` —
   natural-shape clips, padded-matmul Eq. 5) plus the staged
-  allocate/deliver stages; on TPU/GPU it runs the Pallas slab kernel and
+  allocate/deliver stages; on TPU it runs the Pallas slab kernel and
   reuses :func:`repro.core.transition.charge_bookkeeping` for the state
   assembly.
 
@@ -53,9 +53,9 @@ IMPL_ENV_VAR = "CHARGAX_FUSED_IMPL"
 def resolve_impl(impl: str = "auto") -> str:
     """Resolve the fused-step backend: pallas | interpret | ref.
 
-    ``auto`` picks the Pallas kernel on TPU/GPU and the jnp reference on
-    CPU (where the reference is also the bit-exact choice — see
-    :func:`fused_request`).  The ``CHARGAX_FUSED_IMPL`` env var overrides
+    ``auto`` picks the Pallas kernel on TPU (the slab kernel is written to
+    Mosaic's 8x128 tiling) and the jnp reference elsewhere (on CPU the
+    reference is also the bit-exact choice — see :func:`fused_request`).  The ``CHARGAX_FUSED_IMPL`` env var overrides
     ``auto`` (CI uses it to exercise Pallas interpret mode on CPU).
     """
     if impl != "auto":
@@ -63,7 +63,7 @@ def resolve_impl(impl: str = "auto") -> str:
     forced = os.environ.get(IMPL_ENV_VAR, "").strip().lower()
     if forced in ("pallas", "interpret", "ref"):
         return forced
-    return "pallas" if jax.default_backend() in ("tpu", "gpu") else "ref"
+    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
 def _pad_lanes(x: np.ndarray | jnp.ndarray, target: int, fill=0.0):
@@ -180,7 +180,7 @@ def fused_step(
 
     param_arrays = (
         sub(pp.voltage), sub(pp.imax), sub(pp.eff), sub(pp.power_w),
-        pp.member.T, sub(pp.node_budget),
+        pp.member, jnp.broadcast_to(pp.node_budget[:, None], (pp.member.shape[0], 128)),
     )
     outs = chargax_fused_step(
         slab_arrays,
@@ -214,10 +214,10 @@ def fused_request(
 
     Bounds/clip/battery/Eq. 5 all run the staged pipeline's own helpers at
     their natural shapes, so XLA lowers the fused route identically to the
-    staged one — parity is structural, not a tolerance.  (The padded-matmul
-    Eq. 5 reduction lives only in the slab kernel path, where the MXU's
-    reduction order is covered by fp32 tolerance, not bitwise equality:
-    XLA's natural-shape matvec and the 128-lane vecmat associate the sum
+    staged one — parity is structural, not a tolerance.  (The padded
+    Eq. 5 reduction lives only in the slab kernel path, where its reduction
+    order is covered by fp32 tolerance, not bitwise equality: XLA's
+    natural-shape matvec and a 128-lane reduction associate the sum
     differently for some inputs.)
     """
     up, down = pole_bounds(

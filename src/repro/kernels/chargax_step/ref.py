@@ -11,14 +11,15 @@ so one elementwise pipeline serves every pole.  The per-pole physics IS the
 core staged pipeline's — :func:`repro.core.transition.pole_bounds` /
 ``pole_clip`` / ``pole_integrate`` are called directly, so kernel/core
 parity is structural rather than a hand-kept duplicate; only the Eq. 5 tree
-constraint is re-expressed here in its batched matmul form (the shape the
-Pallas kernel's MXU pass mirrors).  ``fused_step_ref`` is the oracle the
-Pallas kernel must match within fp32 op-reorder tolerance.
+constraint is re-expressed here in its batched matmul form.
+``fused_step_ref`` is the oracle the Pallas kernel must match within fp32
+op-reorder tolerance.
 """
 from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.transition import (
@@ -31,12 +32,19 @@ from repro.core.transition import (
 
 __all__ = [
     "BIG",
+    "PARITY_ATOL",
+    "PARITY_RTOL",
     "PoleSlabs",
     "PoleParams",
     "FusedOut",
     "charge_rate",
     "fused_step_ref",
 ]
+
+# fp32 op-reorder tolerance between the compiled kernel (or another
+# backend) and the staged pipeline / this reference
+PARITY_RTOL = 1e-4
+PARITY_ATOL = 2e-4
 
 
 class PoleSlabs(NamedTuple):
@@ -95,8 +103,10 @@ def fused_step_ref(
     i = pole_clip(slabs.target, up, down, slabs.occupied)
 
     # --- Eq. 5 tree constraints (batched matmul form of the core's
-    # constraint_scale; the Pallas kernel mirrors this MXU shape) ------------
-    load = jnp.abs(i) @ pp.member.T  # (..., Nn)
+    # constraint_scale, f32 at HIGHEST like the core's matvec) ---------------
+    load = jnp.matmul(
+        jnp.abs(i), pp.member.T, precision=jax.lax.Precision.HIGHEST
+    )  # (..., Nn)
     s_node = jnp.minimum(1.0, pp.node_budget / jnp.maximum(load, 1e-9))
     excess = jnp.max(jnp.maximum(load - pp.node_budget, 0.0), axis=-1)
     scale = jnp.full_like(i, 1.0)

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.analysis.hlo import collective_stats, cost_analysis_dict
+from repro.analysis.hlo import collective_stats
 from repro.configs.registry import ARCH_IDS, applicable_shapes, build_model, get_config
 from repro.distributed import sharding as shd
 from repro.distributed.train_step import (
@@ -106,7 +106,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, smoke: bool = False,
     rep = NamedSharding(mesh, P())
 
     t0 = time.perf_counter()
-    ctx = shd.set_mesh(mesh)  # ambient mesh for activation constraints
+    ctx = jax.sharding.set_mesh(mesh)  # ambient mesh for activation constraints
     ctx.__enter__()
     if shape.kind == "train":
         mb = microbatches or default_microbatches(cfg, shape, n_dev)
@@ -185,7 +185,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, smoke: bool = False,
         record["fits_16g_hbm"] = bool(args_b + temp_b <= 16 * 2**30)
 
     # --- cost analysis (FLOPs / bytes for §Roofline) -------------------------
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     if cost:
         record["hlo_flops"] = float(cost.get("flops", -1))
         record["hlo_bytes"] = float(cost.get("bytes accessed", -1))

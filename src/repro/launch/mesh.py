@@ -4,26 +4,15 @@
 this module never touches jax device state; the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import and then builds these meshes from host placeholder devices.
-
-``_make_mesh`` wraps ``jax.make_mesh`` across JAX versions: the
-``axis_types`` kwarg only exists on newer releases, and very old ones lack
-``jax.make_mesh`` entirely (fall back to ``Mesh`` over reshaped devices).
 """
 from __future__ import annotations
 
 import jax
-import numpy as np
 
 
 def _make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    make = getattr(jax, "make_mesh", None)
-    if make is not None:
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:
-            return make(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-        return make(shape, axes)
-    n = int(np.prod(shape))
-    return jax.sharding.Mesh(np.asarray(jax.devices()[:n]).reshape(shape), axes)
+    """``jax.make_mesh`` with every axis ``Auto`` (sharding by constraint)."""
+    return jax.make_mesh(shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

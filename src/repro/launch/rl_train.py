@@ -17,16 +17,18 @@ epochs) with the environment batch sharded across the data axes of the
 paper's core claim generalised to pods (DESIGN.md §3).
 """
 import argparse
+import contextlib
 import json
 import time
 
 import jax
 import numpy as np
 
-from repro.analysis.hlo import collective_stats, cost_analysis_dict
+from repro.analysis.hlo import collective_stats
 from repro.core import ChargaxEnv, EnvConfig
-from repro.distributed import env_sharding, sharding
+from repro.distributed import env_sharding
 from repro.rl import PPOConfig, make_train
+from repro.utils import use_accurate_transcendentals, use_compile_cache
 
 # env-batch constraint now lives in the distributed layer, shared with
 # FleetEnv and the benchmarks
@@ -52,14 +54,14 @@ def run_dryrun(args) -> dict:
             num_minibatches=4,
             hidden=(128, 128),
         )
-        with sharding.set_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             train = make_train(cfg, env, shard_envs=make_shard_envs(mesh))
             t0 = time.perf_counter()
             lowered = jax.jit(train).lower(jax.random.key(0))
             compiled = lowered.compile()
             wall = time.perf_counter() - t0
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis() or {}
         rec = {
             "cell": "chargax-ppo-update",
             "mesh": "2x16x16" if multi_pod else "16x16",
@@ -205,37 +207,49 @@ def run_train(args):
     # multi-device: shard the env batch over a data mesh built from every
     # visible device; single device degrades to no mesh / no constraints
     n_dev = jax.device_count()
-    mesh_ctx = None
+    mesh_ctx = contextlib.nullcontext()
     shard_envs = None
-    if n_dev > 1 and cfg.num_envs % n_dev == 0:
+    if n_dev > 1:
+        if cfg.num_envs % n_dev:
+            raise ValueError(
+                f"--num-envs {cfg.num_envs} is not divisible by the {n_dev} "
+                "visible devices: the env batch shards evenly over all of them"
+            )
         from repro.launch.mesh import make_data_mesh
 
         mesh = make_data_mesh()
-        mesh_ctx = sharding.set_mesh(mesh)
+        mesh_ctx = jax.sharding.set_mesh(mesh)
         shard_envs = env_sharding.make_shard_envs(mesh)
         print(f"[ppo] sharding {cfg.num_envs} envs over {n_dev} devices")
-    elif n_dev > 1:
-        print(
-            f"[ppo] WARNING: num_envs={cfg.num_envs} not divisible by "
-            f"{n_dev} devices — env sharding disabled, running replicated"
-        )
 
-    import contextlib
-
-    with mesh_ctx if mesh_ctx is not None else contextlib.nullcontext():
+    steps = cfg.num_updates * cfg.batch_size
+    with mesh_ctx:
         train = jax.jit(
             make_train(cfg, env, shard_envs=shard_envs, scenario_params=scenario_params)
         )
+        key = jax.random.key(args.seed)
         t0 = time.perf_counter()
-        out = train(jax.random.key(args.seed))
-        jax.block_until_ready(out["metrics"]["rollout_reward"])
-        wall = time.perf_counter() - t0
+        compiled = train.lower(key).compile()
+        compile_s = time.perf_counter() - t0
+        mem = compiled.memory_analysis()
+        print(
+            f"[ppo] compiled in {compile_s:.1f}s | device bytes: "
+            f"args={mem.argument_size_in_bytes} out={mem.output_size_in_bytes} "
+            f"temp={mem.temp_size_in_bytes}"
+        )
+        # every update runs inside the one compiled program: nothing may
+        # compile once training starts
+        with obs.compile_guard("rl_train updates"):
+            t0 = time.perf_counter()
+            out = compiled(key)
+            jax.block_until_ready(out["metrics"]["rollout_reward"])
+            wall = time.perf_counter() - t0
         if args.profile:
             _profile_probe(args, cfg, env, shard_envs, scenario_params, obs)
     rr = out["metrics"]["rollout_reward"]
     print(
-        f"[ppo] {args.timesteps:,} steps in {wall:.1f}s "
-        f"({args.timesteps/wall:,.0f} env-steps/s) | "
+        f"[ppo] {steps:,} steps in {wall:.1f}s "
+        f"({steps/wall:,.0f} env-steps/s) | "
         f"reward first->last: {float(rr[0]):.1f} -> {float(rr[-1]):.1f}"
     )
     kpis = {
@@ -271,8 +285,9 @@ def run_train(args):
         )
         writer.write(
             {
+                "compile_s": round(compile_s, 2),
                 "wall_s": round(wall, 2),
-                "env_steps_per_sec": round(args.timesteps / wall, 1),
+                "env_steps_per_sec": round(steps / wall, 1),
                 "rollout_reward_first": float(rr[0]),
                 "rollout_reward_last": float(rr[-1]),
                 "episode_return_last": float(
@@ -310,7 +325,7 @@ def run_train(args):
     if writer is not None:
         writer.close()
         print(f"[obs] metrics JSONL: {writer.path}")
-    return out
+    return {**out, "compile_s": compile_s, "run_s": wall}
 
 
 def main(argv=None):
@@ -336,7 +351,7 @@ def main(argv=None):
         "--fused",
         action="store_true",
         help="route the env step through the fused kernel hot path "
-        "(EnvConfig.fused_step; Pallas on TPU/GPU, bit-exact jnp ref on CPU; "
+        "(EnvConfig.fused_step; Pallas on TPU, bit-exact jnp ref elsewhere; "
         "override with CHARGAX_FUSED_IMPL=pallas|interpret|ref)",
     )
     ap.add_argument("--timesteps", type=int, default=300_000)
@@ -367,6 +382,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if args.dryrun:
         return run_dryrun(args)
+    use_accurate_transcendentals()
+    use_compile_cache()
     return run_train(args)
 
 

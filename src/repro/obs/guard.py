@@ -34,10 +34,11 @@ from typing import Any, Iterator, NamedTuple
 
 import jax
 
-# the logger jax emits "Compiling <name> with global shapes and types
-# [avals...]" records on (at WARNING) while jax.log_compiles() is active
+# the logger jax emits "Compiling jit(<name>) with global shapes and types
+# (<avals>,). Argument mapping: ..." records on (at WARNING) while
+# jax.log_compiles() is active
 _COMPILE_LOGGER = "jax._src.interpreters.pxla"
-_COMPILE_RE = re.compile(r"^Compiling (.+?) with global shapes and types (\[.*\])\. Argument")
+_COMPILE_RE = re.compile(r"^Compiling (.+?) with global shapes and types (.*)\. Argument mapping")
 
 
 class CompileEvent(NamedTuple):

@@ -83,33 +83,12 @@ def _start_trace(log_dir: str, python_tracer: bool) -> None:
     ``len``, …) while tracing runs under the session — for a jit-heavy
     program that is ~100k events per second of trace time and dwarfs the
     phase spans we care about.  Level 0 keeps host ``TraceAnnotation`` spans
-    and device/op events.  Falls back to the public API if jax's internals
-    have moved.
+    and device/op events.
     """
+    opts = jax.profiler.ProfileOptions()
     if not python_tracer:
-        try:
-            from jax._src import profiler as _jprof
-            from jax._src.lib import xla_client as _xc
-
-            opts = _xc.profiler.ProfileOptions()
-            opts.python_tracer_level = 0
-            with _jprof._profile_state.lock:
-                if _jprof._profile_state.profile_session is not None:
-                    raise RuntimeError(
-                        "Profile has already been started. "
-                        "Only one profile may be run at a time."
-                    )
-                _jprof.xla_bridge.get_backend()
-                _jprof._profile_state.profile_session = _xc.profiler.ProfilerSession(
-                    opts
-                )
-                _jprof._profile_state.create_perfetto_link = False
-                _jprof._profile_state.create_perfetto_trace = False
-                _jprof._profile_state.log_dir = str(log_dir)
-            return
-        except (ImportError, AttributeError):  # pragma: no cover - jax drift
-            pass
-    jax.profiler.start_trace(log_dir)
+        opts.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=opts)
 
 
 @contextlib.contextmanager

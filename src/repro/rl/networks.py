@@ -6,6 +6,7 @@ pole plus one for the battery (paper: discretisation level 10 per port).
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
@@ -22,7 +23,7 @@ def orthogonal(key: jax.Array, shape: tuple[int, int], scale: float) -> jnp.ndar
     return scale * q[:n_rows, :n_cols]
 
 
-def dense_init(key, in_dim, out_dim, scale=jnp.sqrt(2.0)):
+def dense_init(key, in_dim, out_dim, scale=math.sqrt(2.0)):
     return {
         "w": orthogonal(key, (in_dim, out_dim), scale),
         "b": jnp.zeros((out_dim,), jnp.float32),

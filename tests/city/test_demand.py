@@ -173,7 +173,7 @@ def test_sharded_city_coupled_fleet_matches_unsharded():
     ref_rates, ref_profit = rollout(FleetEnv(archs, city=city, shard=False), None)
     fleet = FleetEnv(archs, city=city)
     mesh = make_data_mesh()
-    with sharding.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = env_sharding.place_env_batch(fleet.default_params, mesh)
         got_rates, got_profit = rollout(fleet, params)
 

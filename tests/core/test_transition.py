@@ -10,6 +10,8 @@ from repro.core.transition import (
     constraint_scale,
     decode_action,
     discharge_rate,
+    pole_bounds,
+    pole_integrate,
 )
 from repro.utils import replace
 
@@ -158,6 +160,24 @@ def test_charge_sensitive_car_departs_when_full(env, params):
     # car got its 0.5 kWh and left: port free or re-occupied by a new arrival,
     # but its early-finish recorded nothing in overtime
     assert float(s2.overtime_steps_cum) == 0.0
+
+
+@pytest.mark.parametrize("dt_hours", [5 / 60, 15 / 60, 1.0])
+def test_request_bounded_step_zeroes_the_request(dt_hours):
+    """A current at the request bound leaves no rounding residual; one ulp
+    less leaves the residual ``e_remain - e`` (a car stays charging)."""
+    e_req = jnp.linspace(0.01, 90.0, 4097, dtype=jnp.float32)
+    soc, cap, rbar, tau = (jnp.full_like(e_req, v) for v in (0.05, 100.0, 1e4, 0.99))
+    volts, imax = jnp.float32(500.0), jnp.float32(1e6)
+    up, _ = pole_bounds(soc, e_req, cap, rbar, tau, volts, imax, 1.0, dt_hours)
+    below = jnp.nextafter(up, 0.0)
+
+    def remain(current):
+        return pole_integrate(soc, e_req, cap, rbar, tau, 1.0, volts, current, 1.0, dt_hours)[2]
+
+    np.testing.assert_array_equal(remain(up), 0.0)
+    rem = remain(below)
+    assert bool(jnp.all(rem >= 0.0)) and bool(jnp.any(rem > 0.0))
 
 
 def test_episode_terminates(env):

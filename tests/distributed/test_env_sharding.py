@@ -117,7 +117,7 @@ def test_sharded_fleet_rollout_matches_unsharded():
 
     ref = _fleet_rollout(FleetEnv(archs, shard=False), None)
     fleet = FleetEnv(archs)
-    with sharding.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = env_sharding.place_env_batch(fleet.default_params, mesh)
         if n_dev > 1:
             # tables really are distributed over the devices
@@ -141,7 +141,7 @@ def test_sharded_scenario_ppo_matches_unsharded():
         jax.jit(make_train(cfg, ENV, scenario_params=stacked))(key)["metrics"]
     )
     mesh = make_data_mesh()
-    with sharding.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         train = make_train(
             cfg,
             ENV,
@@ -162,7 +162,7 @@ def test_two_device_mesh_distributes_env_batch():
     mesh = make_data_mesh()
     n_dev = jax.device_count()
     fleet = FleetEnv(["paper_16"] * (2 * n_dev))
-    with sharding.set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         params = env_sharding.place_env_batch(fleet.default_params, mesh)
         obs, state = jax.jit(fleet.reset)(jax.random.key(0), params)
     assert len(params.evse_mask.sharding.device_set) == n_dev

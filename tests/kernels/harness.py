@@ -40,6 +40,7 @@ except ImportError:  # pragma: no cover - exercised in minimal installs
 from repro.core import ChargaxEnv, EnvConfig, transition
 from repro.core.transition import BIG
 from repro.kernels.chargax_step import ops as fused_ops
+from repro.kernels.chargax_step.ref import PARITY_ATOL, PARITY_RTOL
 from repro.utils import replace
 
 # the four canonical action modes of the acceptance criteria
@@ -143,7 +144,9 @@ def assert_trees_equal(got, want, context: str = ""):
         )
 
 
-def assert_trees_close(got, want, context: str = "", rtol=1e-4, atol=2e-4):
+def assert_trees_close(
+    got, want, context: str = "", rtol=PARITY_RTOL, atol=PARITY_ATOL
+):
     """fp32 op-reorder tolerance over two pytrees (pallas/interpret impls)."""
     gl, gt = jax.tree_util.tree_flatten(got)
     wl, wt = jax.tree_util.tree_flatten(want)

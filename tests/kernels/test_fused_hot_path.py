@@ -137,7 +137,7 @@ def test_resolve_impl_env_var_override(monkeypatch):
     monkeypatch.setenv(fused_ops.IMPL_ENV_VAR, "pallas")
     assert fused_ops.resolve_impl() == "pallas"
     monkeypatch.delenv(fused_ops.IMPL_ENV_VAR)
-    expected = "pallas" if jax.default_backend() in ("tpu", "gpu") else "ref"
+    expected = "pallas" if jax.default_backend() == "tpu" else "ref"
     assert fused_ops.resolve_impl() == expected
     assert fused_ops.resolve_impl("ref") == "ref"  # explicit beats env/auto
 

@@ -24,7 +24,7 @@ from repro.configs.registry import build_model, get_config
 from repro.distributed import sharding as shd
 from repro.distributed.train_step import TrainStepConfig, TrainState, make_train_step, make_serve_step
 from repro.optim import AdamWState
-from repro.analysis.hlo import collective_stats, cost_analysis_dict
+from repro.analysis.hlo import collective_stats
 
 mesh = jax.make_mesh((2, 4), ("data", "model"))
 assert mesh.devices.size == 8
@@ -55,7 +55,7 @@ batch_sh = jax.tree_util.tree_map(lambda s: s.sharding, batch)
 
 lowered = jax.jit(step, in_shardings=(state_sh, batch_sh), donate_argnums=(0,)).lower(state_abs, batch)
 compiled = lowered.compile()
-cost = cost_analysis_dict(compiled)
+cost = compiled.cost_analysis() or {}
 coll = collective_stats(compiled.as_text())
 mem = compiled.memory_analysis()
 
