@@ -427,13 +427,15 @@ class ChargaxEnv(Environment):
             "grid/setpoint_dev": settled.setpoint_dev_kw,
         }
         with annotate("env/observe"):
-            obs = self.observe(new_state, params)
+            obs = self.observe(new_state, params, label_price_window=True)
         return TimeStep(obs, new_state, settled.reward, done, info)
 
     # ------------------------------------------------------------------
     # Observation
     # ------------------------------------------------------------------
-    def observe(self, state: EnvState, params: EnvParams) -> jnp.ndarray:
+    def observe(
+        self, state: EnvState, params: EnvParams, label_price_window: bool = False
+    ) -> jnp.ndarray:
         cfg = self.config
         spd = cfg.steps_per_day
         return transition.observe(
@@ -442,6 +444,7 @@ class ChargaxEnv(Environment):
             steps_per_day=spd,
             horizon_steps=max(int(cfg.obs_price_horizon_hours * spd / 24), 1),
             near_steps=max(int(spd / 24), 1),
+            label_price_window=label_price_window,
         )
 
 

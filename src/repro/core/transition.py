@@ -36,6 +36,7 @@ invariant over the whole scenario catalog survives.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import NamedTuple
 
 import jax
@@ -43,6 +44,7 @@ import jax.numpy as jnp
 
 from repro.core.rewards import PenaltyTerms, StepEnergies, compute_reward, step_energies
 from repro.core.state import EnvParams, EnvState
+from repro.obs import annotate
 from repro.utils import replace
 
 # Energy-request sentinel for poles with no finite request (the station
@@ -553,90 +555,97 @@ def arrive_cars(
     rate_extra: jnp.ndarray | None = None,
 ) -> ArriveResult:
     n = state.occupied.shape[0]
-    k_m, k_port = jax.random.split(key)
-
     spd = params.arrival_rate.shape[0]
-    n_days = params.arrival_day_scale.shape[0]
-    rate = params.arrival_rate[jnp.mod(state.t, spd)] * params.arrival_day_scale[
-        jnp.mod(state.day, n_days)
-    ]
-    if rate_extra is not None:
-        # city coupling: the station's allocated share of the population-scale
-        # arrival stream (repro.city) adds to its own walk-in table; a zero
-        # share leaves the Poisson rate bit-identical to the uncoupled step
-        rate = rate + rate_extra
-    m = jax.random.poisson(k_m, rate).astype(jnp.int32)
+    with annotate("env/arrive_count"):
+        k_m, k_port = jax.random.split(key)
 
-    # padded fleet lanes (evse_mask == 0) never accept cars
-    free = (state.occupied < 0.5) & (params.evse_mask > 0.5)
-    n_free = jnp.sum(free.astype(jnp.int32))
-    n_arrive = jnp.minimum(m, n_free)
-    n_reject = jnp.maximum(m - n_free, 0)
+        n_days = params.arrival_day_scale.shape[0]
+        rate = params.arrival_rate[jnp.mod(state.t, spd)] * params.arrival_day_scale[
+            jnp.mod(state.day, n_days)
+        ]
+        if rate_extra is not None:
+            # city coupling: the station's allocated share of the population-scale
+            # arrival stream (repro.city) adds to its own walk-in table; a zero
+            # share leaves the Poisson rate bit-identical to the uncoupled step
+            rate = rate + rate_extra
+        m = jax.random.poisson(k_m, rate).astype(jnp.int32)
 
-    # first-come-first-served: fill free ports in index order
-    rank = jnp.cumsum(free.astype(jnp.int32))  # 1-based among free ports
-    assign = free & (rank <= n_arrive)
-    a = assign.astype(jnp.float32)
+        # padded fleet lanes (evse_mask == 0) never accept cars
+        free = (state.occupied < 0.5) & (params.evse_mask > 0.5)
+        n_free = jnp.sum(free.astype(jnp.int32))
+        n_arrive = jnp.minimum(m, n_free)
+        n_reject = jnp.maximum(m - n_free, 0)
 
-    # fleet-mix drift: a (365, n_models) table selects the day's distribution
-    probs = (
-        params.car_probs
-        if params.car_probs.ndim == 1
-        else params.car_probs[jnp.mod(state.day, params.car_probs.shape[0])]
-    )
+        # first-come-first-served: fill free ports in index order
+        rank = jnp.cumsum(free.astype(jnp.int32))  # 1-based among free ports
+        assign = free & (rank <= n_arrive)
+        a = assign.astype(jnp.float32)
 
     # --- per-port profile draws (one draw per port; only assigned ports
     # consume it).  Keys are folded per port index so the draw on port i is
     # independent of n — padding a station with extra lanes leaves the real
     # lanes' trajectories bit-for-bit unchanged (FleetEnv regression tests).
-    def draw_port(i):
-        k_model, k_stay, k_soc0, k_tgt, k_u = jax.random.split(
-            jax.random.fold_in(k_port, i), 5
+    with annotate("env/draw_cars"):
+        with annotate("env/draw_model"):
+            # fleet-mix drift: a (365, n_models) table selects the day's distribution
+            probs = (
+                params.car_probs
+                if params.car_probs.ndim == 1
+                else params.car_probs[jnp.mod(state.day, params.car_probs.shape[0])]
+            )
+
+        def draw_port(i):
+            k_model, k_stay, k_soc0, k_tgt, k_u = jax.random.split(
+                jax.random.fold_in(k_port, i), 5
+            )
+            with annotate("env/draw_model"):
+                model = jax.random.choice(k_model, probs.shape[0], p=probs)
+            z_stay = jax.random.normal(k_stay, ())
+            with annotate("env/draw_soc0"):
+                soc0 = jax.random.beta(k_soc0, params.soc0_a, params.soc0_b)
+            z_tgt = jax.random.normal(k_tgt, ())
+            bern = jax.random.bernoulli(k_u, params.p_time_sensitive)
+            return model, z_stay, soc0, z_tgt, bern
+
+        model, z_stay, soc0_raw, z_tgt, bern = jax.vmap(draw_port)(jnp.arange(n))
+
+    # --- car profiles: the per-port model-table lookups -----------------------
+    with annotate("env/car_lookup"):
+        cap = params.car_capacity[model]
+        tau = params.car_tau[model]
+        car_kw = jnp.where(
+            params.evse_is_dc > 0.5, params.car_dc_kw[model], params.car_ac_kw[model]
         )
-        model = jax.random.choice(k_model, probs.shape[0], p=probs)
-        z_stay = jax.random.normal(k_stay, ())
-        soc0 = jax.random.beta(k_soc0, params.soc0_a, params.soc0_b)
-        z_tgt = jax.random.normal(k_tgt, ())
-        bern = jax.random.bernoulli(k_u, params.p_time_sensitive)
-        return model, z_stay, soc0, z_tgt, bern
+        rbar = car_kw * 1000.0 / params.evse_voltage  # car-side current limit [A]
 
-    model, z_stay, soc0_raw, z_tgt, bern = jax.vmap(draw_port)(jnp.arange(n))
+    # --- user profiles and the placement itself --------------------------------
+    with annotate("env/place_cars"):
+        stay_h = jnp.exp(params.stay_mu_log + params.stay_sigma * z_stay)
+        steps_per_hour = spd / 24.0
+        stay_steps = jnp.maximum((stay_h * steps_per_hour).astype(jnp.int32), 1)
+        soc0 = jnp.clip(soc0_raw, 0.02, 0.95)
+        target = jnp.clip(
+            params.target_soc_mu + params.target_soc_std * z_tgt, soc0 + 0.05, 1.0
+        )
+        e_req = (target - soc0) * cap
+        # u: 0 = time-sensitive (leaves at deadline), 1 = charge-sensitive
+        u = 1.0 - bern.astype(jnp.float32)
 
-    # --- car profiles --------------------------------------------------------
-    cap = params.car_capacity[model]
-    tau = params.car_tau[model]
-    car_kw = jnp.where(
-        params.evse_is_dc > 0.5, params.car_dc_kw[model], params.car_ac_kw[model]
-    )
-    rbar = car_kw * 1000.0 / params.evse_voltage  # car-side current limit [A]
-
-    # --- user profiles -------------------------------------------------------
-    stay_h = jnp.exp(params.stay_mu_log + params.stay_sigma * z_stay)
-    steps_per_hour = spd / 24.0
-    stay_steps = jnp.maximum((stay_h * steps_per_hour).astype(jnp.int32), 1)
-    soc0 = jnp.clip(soc0_raw, 0.02, 0.95)
-    target = jnp.clip(
-        params.target_soc_mu + params.target_soc_std * z_tgt, soc0 + 0.05, 1.0
-    )
-    e_req = (target - soc0) * cap
-    # u: 0 = time-sensitive (leaves at deadline), 1 = charge-sensitive
-    u = 1.0 - bern.astype(jnp.float32)
-
-    new_state = replace(
-        state,
-        occupied=state.occupied * (1 - a) + a,
-        soc=state.soc * (1 - a) + a * soc0,
-        e_remain=state.e_remain * (1 - a) + a * e_req,
-        v2g_debt=state.v2g_debt * (1 - a),  # fresh arrivals carry no debt
-        t_remain=jnp.where(assign, stay_steps, state.t_remain),
-        rhat=state.rhat * (1 - a) + a * charge_rate(soc0, rbar, tau),
-        cap=state.cap * (1 - a) + a * cap,
-        rbar=state.rbar * (1 - a) + a * rbar,
-        tau=jnp.where(assign, tau, state.tau),
-        user_type=state.user_type * (1 - a) + a * u,
-        cars_served=state.cars_served + n_arrive.astype(jnp.float32),
-        cars_rejected=state.cars_rejected + n_reject.astype(jnp.float32),
-    )
+        new_state = replace(
+            state,
+            occupied=state.occupied * (1 - a) + a,
+            soc=state.soc * (1 - a) + a * soc0,
+            e_remain=state.e_remain * (1 - a) + a * e_req,
+            v2g_debt=state.v2g_debt * (1 - a),  # fresh arrivals carry no debt
+            t_remain=jnp.where(assign, stay_steps, state.t_remain),
+            rhat=state.rhat * (1 - a) + a * charge_rate(soc0, rbar, tau),
+            cap=state.cap * (1 - a) + a * cap,
+            rbar=state.rbar * (1 - a) + a * rbar,
+            tau=jnp.where(assign, tau, state.tau),
+            user_type=state.user_type * (1 - a) + a * u,
+            cars_served=state.cars_served + n_arrive.astype(jnp.float32),
+            cars_rejected=state.cars_rejected + n_reject.astype(jnp.float32),
+        )
     return ArriveResult(new_state, n_arrive, n_reject)
 
 
@@ -661,7 +670,8 @@ def depart_arrive(
     into the Poisson draw — the per-station input the city demand-allocation
     layer computes each step instead of a fixed table.
     """
-    departed = depart_cars(state)
+    with annotate("env/departures"):
+        departed = depart_cars(state)
     key, k_arr = jax.random.split(key)
     arrived = arrive_cars(params, departed.state, k_arr, rate_extra)
     return DepartArriveResult(
@@ -775,8 +785,15 @@ def observe(
     steps_per_day: int,
     horizon_steps: int,
     near_steps: int,
+    label_price_window: bool = False,
 ) -> jnp.ndarray:
-    """Flat float32 observation (see ``ChargaxEnv.observation_space``)."""
+    """Flat float32 observation (see ``ChargaxEnv.observation_space``).
+
+    ``label_price_window`` names the price-horizon lookup ``env/price_window``.
+    The step sets it; ``reset`` does not, so reset's lookup, which
+    ``AutoReset`` runs on every step, stays under the wrapper's scope and
+    outside the ``env/*`` stages.
+    """
     spd = steps_per_day
     imax = params.evse_max_current
     port_feats = jnp.stack(
@@ -804,9 +821,10 @@ def observe(
     time_feats = jnp.stack(
         [jnp.sin(phase), jnp.cos(phase), weekday, state.day.astype(jnp.float32) / 365.0]
     )
-    idx = jnp.mod(state.t, spd)
-    ahead = state.price_buy[jnp.mod(idx + jnp.arange(horizon_steps), spd)]
-    price_feats = jnp.stack(
-        [state.price_buy[idx], jnp.mean(ahead[:near_steps]), jnp.mean(ahead)]
-    )
+    with annotate("env/price_window") if label_price_window else contextlib.nullcontext():
+        idx = jnp.mod(state.t, spd)
+        ahead = state.price_buy[jnp.mod(idx + jnp.arange(horizon_steps), spd)]
+        price_feats = jnp.stack(
+            [state.price_buy[idx], jnp.mean(ahead[:near_steps]), jnp.mean(ahead)]
+        )
     return jnp.concatenate([port_feats, batt_feats, time_feats, price_feats])

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 from typing import Any, NamedTuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -126,27 +125,3 @@ class MetricsAccumulator(NamedTuple):
         out["steps"] = float(np.asarray(self.count).mean()) if reduce_batch else np.asarray(self.count)
         return out
 
-
-def kpi_summary(acc: MetricsAccumulator, prefix: str = "kpi/") -> dict[str, jnp.ndarray]:
-    """Batch-mean device scalars for every tracked sum (still traced — used
-    by PPO to emit per-update KPI metrics without leaving the jit)."""
-    out = {f"{prefix}{n}": s.mean() for n, s in acc.sums.items()}
-    for n, m in acc.maxes.items():
-        out[f"{prefix}{n}_max"] = m.max()
-    return out
-
-
-def _is_acc(x: Any) -> bool:
-    return isinstance(x, MetricsAccumulator)
-
-
-def tree_find_accumulators(tree: Any) -> list[MetricsAccumulator]:
-    """Collect every :class:`MetricsAccumulator` inside an arbitrary pytree
-    (e.g. a wrapper state) — how hosts locate the KPIs to flush."""
-    found: list[MetricsAccumulator] = []
-    jax.tree_util.tree_map(
-        lambda x: found.append(x) if _is_acc(x) else None,
-        tree,
-        is_leaf=_is_acc,
-    )
-    return found

@@ -4,31 +4,61 @@
 traced inside it, so phases show up in a profile and in HLO metadata) plus
 ``jax.profiler.TraceAnnotation`` (marks the host thread, so host-side phases
 show as spans).  Annotations are **disabled by default** and the disabled
-path is a bare ``yield`` — the compiled program is byte-identical with the
-subsystem off, which the speed benchmark relies on
-(``benchmarks/speed_table.py`` proves raw-vs-wrapped HLO equality).
+path is a bare ``yield``: the compiled program and its op metadata are the
+same with the subsystem off as without the ``annotate`` calls
+(``tests/obs/test_trace.py::test_annotate_is_a_bare_noop_when_disabled``,
+and ``tests/obs/test_scopes.py`` for every scope of the env step and the
+PPO update).
 
 Enable them either with :func:`enable_trace_annotations` /
 ``REPRO_TRACE=1``, or implicitly via :func:`trace_session`, which wraps
 ``jax.profiler.start_trace``/``stop_trace`` and yields a perfetto-viewable
 ``*.trace.json.gz`` (open at https://ui.perfetto.dev).  ``rl_train
---profile DIR`` is the CLI surface.
+--profile DIR`` is the CLI surface; ``bench/run.py --trace 1`` turns them on
+for the benchmark's traced runs.
 
-Phase-name catalog (see ``docs/observability.md``):
+Phase-name catalog (see ``docs/observability.md``).  An indented name sits
+inside the one above it.  The last column names the ``bench/metrics``
+reader that reads the scope; ``env/*`` and ``wrap/*`` are also read as
+wholes (``env_step_ns.*``, ``env_step_roofline.sim``: all of ``env/``;
+``wrap_self_ns.sim``: ``wrap/`` outside ``env/``).
 
-=====================  ==================================================
-``env/decode``          action decoding (direct / delta modes)
-``env/apply_actions``   Eq. 5 constrained current allocation
-``env/charge_cars``     battery/car energy integration + V2G debt
-``env/depart_arrive``   departures, arrivals, rejections
-``env/reward``          Eq. 2 revenue + penalty terms
-``env/observe``         observation build
-``wrap/<Wrapper>``      each wrapper layer's step (Vmap, AutoReset, Log…)
-``ppo/rollout``         the rollout scan
-``ppo/gae``             advantage estimation
-``ppo/update``          minibatch epochs
-``eval/rollout``        evaluation episodes
-=====================  ==================================================
+=========================  ==========================================  ========================
+``env/decode``             action decoding (direct / delta modes)      ``env/`` only
+``env/apply_actions``      request: Eq. 5 constrained currents         ``env/`` only
+``env/allocate``           feeder-cap curtailment                      ``env/`` only
+``env/fused_transition``   fused kernel route (request..deliver)       none: no cell runs it
+``env/charge_cars``        deliver: energy integration + V2G debt      ``env/`` only
+``env/depart_arrive``      departures, arrivals, rejections            ``env/`` only
+  ``env/departures``       departure masks and state clears            ``env/`` only
+  ``env/arrive_count``     rate lookup, Poisson draw, FCFS rank        ``env/`` only
+  ``env/draw_cars``        every port's profile draws                  ``env/`` only
+    ``env/draw_model``     car-model draw (``random.choice``)          ``draw_model_ns.sim``
+    ``env/draw_soc0``      arrival SoC draw (``random.beta``)          ``env/`` only
+  ``env/car_lookup``       per-port model-table gathers                ``car_lookup_ns.sim``
+  ``env/place_cars``       user profiles and the state writes          ``env/`` only
+``env/reward``             settle: Eq. 1-3 reward, grid penalties      ``env/`` only
+``env/observe``            the step's observation build                ``env/`` only
+  ``env/price_window``     price-horizon gather                        ``price_window_ns.train``
+``wrap/<Wrapper>``         each wrapper layer's step (Vmap, AutoReset  ``wrap_self_ns.sim``
+                           reset and select, Log, FleetAdapter)
+``ppo/rollout``            the rollout scan                            ``ppo_rollout_ms``
+``ppo/gae``                advantage estimation                        none
+``ppo/update``             minibatch epochs                            ``ppo_update_ms``
+  ``ppo/shuffle``          permutation, gather, minibatch reshapes     ``ppo_shuffle_ms``
+  ``ppo/optim``            AdamW update and apply                      none
+``ppo/mlp``                actor-critic forward; backward ops read     ``ppo_update_mlp_ms``
+                           ``transpose(jvp(ppo/mlp))``                 (ops also in ``ppo/update``)
+``ppo/logprob``            factorised categorical log-probability      ``ppo_logprob_ms``
+``eval/rollout``           evaluation episodes                         none
+``eval/serve``             batched policy inference step               none
+``profile/*``              ``rl_train --profile`` host windows         none
+=========================  ==========================================  ========================
+
+No name is a prefix of another: the benchmark's reducer matches scopes by
+prefix.  ``env/price_window`` is set by the step's observation only, so
+the copy that ``reset`` (under ``AutoReset``) computes stays outside
+``env/*``.
 """
 from __future__ import annotations
 

@@ -162,7 +162,12 @@ def make_train(
     wenv = LogWrapper(AutoReset(venv), metrics=tuple(kpi_metrics))
 
     def policy(params, obs):
-        return networks.apply_actor_critic(params, obs, n_heads, n_actions)
+        with annotate("ppo/mlp"):
+            return networks.apply_actor_critic(params, obs, n_heads, n_actions)
+
+    def log_prob(logits, action):
+        with annotate("ppo/logprob"):
+            return networks.log_prob(logits, action)
 
     def train(key: jax.Array) -> dict:
         key, k_net, k_reset = jax.random.split(key, 3)
@@ -178,7 +183,7 @@ def make_train(
             key, k_act, k_env = jax.random.split(key, 3)
             out = policy(params, obs)
             action = networks.sample_action(k_act, out.logits)
-            logp = networks.log_prob(out.logits, action)
+            logp = log_prob(out.logits, action)
 
             # step + auto-reset: ts.obs/ts.state restart where done, while
             # ts.reward/ts.done still describe the finishing transition
@@ -208,7 +213,7 @@ def make_train(
 
         def loss_fn(params, batch: Transition, gae, targets):
             out = policy(params, batch.obs)
-            logp = networks.log_prob(out.logits, batch.action)
+            logp = log_prob(out.logits, batch.action)
             ratio = jnp.exp(logp - batch.log_prob)
             gae_n = (gae - gae.mean()) / (gae.std() + 1e-8)
             pg1 = ratio * gae_n
@@ -231,23 +236,25 @@ def make_train(
             (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, batch[0], batch[1], batch[2]
             )
-            updates, opt_state, gnorm = adamw_update(grads, opt_state, params, lr, opt_cfg)
-            params = apply_updates(params, updates)
+            with annotate("ppo/optim"):
+                updates, opt_state, gnorm = adamw_update(grads, opt_state, params, lr, opt_cfg)
+                params = apply_updates(params, updates)
             return (params, opt_state), {"loss": loss, "grad_norm": gnorm, **aux}
 
         def update_epoch(carry, _):
             params, opt_state, traj, gae, targets, key = carry
             key, k_perm = jax.random.split(key)
             bs = config.batch_size
-            perm = jax.random.permutation(k_perm, bs)
+            with annotate("ppo/shuffle"):
+                perm = jax.random.permutation(k_perm, bs)
 
-            flat = jax.tree_util.tree_map(
-                lambda x: x.reshape((bs,) + x.shape[2:]), (traj, gae, targets)
-            )
-            shuffled = jax.tree_util.tree_map(lambda x: jnp.take(x, perm, axis=0), flat)
-            minibatches = jax.tree_util.tree_map(
-                lambda x: x.reshape((config.num_minibatches, -1) + x.shape[1:]), shuffled
-            )
+                flat = jax.tree_util.tree_map(
+                    lambda x: x.reshape((bs,) + x.shape[2:]), (traj, gae, targets)
+                )
+                shuffled = jax.tree_util.tree_map(lambda x: jnp.take(x, perm, axis=0), flat)
+                minibatches = jax.tree_util.tree_map(
+                    lambda x: x.reshape((config.num_minibatches, -1) + x.shape[1:]), shuffled
+                )
             (params, opt_state), metrics = jax.lax.scan(
                 update_minibatch, (params, opt_state), minibatches
             )

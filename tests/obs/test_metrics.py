@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 
 from repro.obs import MetricsAccumulator
-from repro.obs.metrics import kpi_summary, tree_find_accumulators
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -101,17 +100,3 @@ def test_flush_totals_means_and_maxes():
     per_lane = acc.flush(reduce_batch=False)
     assert np.allclose(per_lane["profit"], [2.0, 6.0])
 
-
-def test_kpi_summary_stays_on_device():
-    acc = MetricsAccumulator.create(("profit",), batch_shape=(3,))
-    acc = acc.update({"profit": jnp.arange(3.0)})
-    out = jax.jit(kpi_summary)(acc)  # traced — no host sync required
-    assert float(out["kpi/profit"]) == pytest.approx(1.0)
-
-
-def test_tree_find_accumulators():
-    acc = MetricsAccumulator.create(("profit",))
-    tree = {"a": [1, {"b": acc}], "c": (acc,)}
-    found = tree_find_accumulators(tree)
-    assert len(found) == 2 and all(isinstance(f, MetricsAccumulator) for f in found)
-    assert tree_find_accumulators({"x": jnp.zeros(2)}) == []
