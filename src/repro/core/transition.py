@@ -548,6 +548,28 @@ class ArriveResult(NamedTuple):
     n_rejected: jnp.ndarray  # ()
 
 
+def draw_car_model(key: jax.Array, cdf: jnp.ndarray) -> jnp.ndarray:
+    """``jax.random.choice(key, M, p=probs)`` for ``cdf = cumsum(probs)``, bit
+    for bit: the same uniform, searched by counting the CDF entries below it.
+
+    ``sum(cdf < r)`` is ``searchsorted(cdf, r, side="left")``; over a handful
+    of models it is a few elementwise compares, where the search is a loop of
+    gathers once the draw is batched over ports and envs.
+    """
+    r = cdf[-1] * (1 - jax.random.uniform(key, (), cdf.dtype))
+    return jnp.sum(cdf < r, dtype=jnp.int32)
+
+
+def select_car_row(table: jnp.ndarray, model: jnp.ndarray) -> jnp.ndarray:
+    """``table[model]`` for a short model table, by one select per model row
+    instead of a gather (an index past the last row reads the last row, as the
+    gather's clamp does)."""
+    out = table[-1]
+    for m in range(table.shape[0] - 1):
+        out = jnp.where(model == m, table[m], out)
+    return out
+
+
 def arrive_cars(
     params: EnvParams,
     state: EnvState,
@@ -593,13 +615,14 @@ def arrive_cars(
                 if params.car_probs.ndim == 1
                 else params.car_probs[jnp.mod(state.day, params.car_probs.shape[0])]
             )
+            cdf = jnp.cumsum(probs)
 
         def draw_port(i):
             k_model, k_stay, k_soc0, k_tgt, k_u = jax.random.split(
                 jax.random.fold_in(k_port, i), 5
             )
             with annotate("env/draw_model"):
-                model = jax.random.choice(k_model, probs.shape[0], p=probs)
+                model = draw_car_model(k_model, cdf)
             z_stay = jax.random.normal(k_stay, ())
             with annotate("env/draw_soc0"):
                 soc0 = jax.random.beta(k_soc0, params.soc0_a, params.soc0_b)
@@ -611,10 +634,12 @@ def arrive_cars(
 
     # --- car profiles: the per-port model-table lookups -----------------------
     with annotate("env/car_lookup"):
-        cap = params.car_capacity[model]
-        tau = params.car_tau[model]
+        cap = select_car_row(params.car_capacity, model)
+        tau = select_car_row(params.car_tau, model)
         car_kw = jnp.where(
-            params.evse_is_dc > 0.5, params.car_dc_kw[model], params.car_ac_kw[model]
+            params.evse_is_dc > 0.5,
+            select_car_row(params.car_dc_kw, model),
+            select_car_row(params.car_ac_kw, model),
         )
         rbar = car_kw * 1000.0 / params.evse_voltage  # car-side current limit [A]
 

@@ -33,9 +33,9 @@ wholes (``env_step_ns.*``, ``env_step_roofline.sim``: all of ``env/``;
   ``env/departures``       departure masks and state clears            ``env/`` only
   ``env/arrive_count``     rate lookup, Poisson draw, FCFS rank        ``env/`` only
   ``env/draw_cars``        every port's profile draws                  ``env/`` only
-    ``env/draw_model``     car-model draw (``random.choice``)          ``draw_model_ns.sim``
+    ``env/draw_model``     compare-and-count over the fleet mix's CDF  ``draw_model_ns.sim``
     ``env/draw_soc0``      arrival SoC draw (``random.beta``)          ``env/`` only
-  ``env/car_lookup``       per-port model-table gathers                ``car_lookup_ns.sim``
+  ``env/car_lookup``       per-port selects over the model rows        ``car_lookup_ns.sim``
   ``env/place_cars``       user profiles and the state writes          ``env/`` only
 ``env/reward``             settle: Eq. 1-3 reward, grid penalties      ``env/`` only
 ``env/observe``            the step's observation build                ``env/`` only

@@ -7,15 +7,19 @@ interpret mode cannot show.  The topology is described inside a fixture, so
 only the worker that runs these tests loads libtpu; where it cannot be
 described, every test here skips.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import scenarios
 from repro.core import ChargaxEnv, EnvConfig
-from repro.envs import VmapWrapper
+from repro.envs import AutoReset, VmapWrapper
 from repro.kernels.chargax_step import ops
 from repro.kernels.chargax_step.kernel import chargax_fused_step
+from repro.obs import enable_trace_annotations
 
 N_ENVS = 4096
 P = 128  # one lane tile of poles: the 16-EVSE station + battery, padded
@@ -76,3 +80,31 @@ def test_fused_vmapped_env_step_compiles_for_v5e(one_chip, monkeypatch):
     args = _spec((key, state, action, params), one_chip)
     text = jax.jit(venv.step).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_car_model_draw_and_lookup_compile_without_gathers_for_v5e(one_chip):
+    """The benchmark's nested step (four shopping scenarios x 1024 envs, 16
+    ports): the car-model draw and the model-table lookup compile to no
+    ``while`` and no per-port gather. The one gather left under
+    ``env/draw_model`` reads each env's row of its scenario's drift table."""
+    names = ("shopping_flat", "shopping_pv_tou", "shopping_fleet_drift", "real_nl_2024_shopping_tou")
+    env = ChargaxEnv(EnvConfig())
+    params = scenarios.stack_params([scenarios.make(n).make_params(env) for n in names])
+    venv = AutoReset(VmapWrapper(env, N_ENVS, num_scenarios=len(names)))
+    key = jax.random.key(0)
+    _, state = jax.eval_shape(venv.reset, key, params)
+    action = jax.eval_shape(venv.sample_action, key)
+    prev = enable_trace_annotations(True)
+    try:
+        text = jax.jit(venv.step).lower(*_spec((key, state, action, params), one_chip)).compile().as_text()
+    finally:
+        enable_trace_annotations(prev)
+    found = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        op = re.search(r"= .*? ([a-z][a-z0-9-]*)\(", line)
+        if name and op and ("env/draw_model" in name.group(1) or "env/car_lookup" in name.group(1)):
+            found.append((op.group(1), name.group(1)))
+    assert any("env/car_lookup" in n for _, n in found)
+    bad = [(op, n) for op, n in found if op in ("gather", "while")]
+    assert [b for b in bad if not b[1].endswith("/env/draw_cars/env/draw_model/gather")] == []
