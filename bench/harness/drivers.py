@@ -8,10 +8,18 @@ numbers that decide ``correct``.
 Each call ``i`` takes its randomness from ``fold_in(stream(seed, "calls"),
 i)`` inside the compiled program, so a call needs no new key on the host
 and one seed gives one sequence of inputs.
+
+A driver runs on the devices it is given.  On more than one, the PPO
+driver takes ``rl_train``'s data-parallel path: ``make_data_mesh`` over
+the devices, ``make_shard_envs`` of that mesh into ``make_train``, and the
+program lowered under ``set_mesh``; the scenario tables are replicated on
+the mesh, and the reference is sharded alike.
 """
 from __future__ import annotations
 
+import contextlib
 import gc
+import re
 import time
 
 import numpy as np
@@ -32,17 +40,71 @@ def stream(seed: int, name: str):
     return jax.random.fold_in(key, {"calls": 0, "reset": 1}[name])
 
 
-def _first_device_put(tree):
-    import jax
+def data_mesh(devices: list, num_envs: int):
+    """``rl_train``'s data mesh over ``devices`` (``make_data_mesh``, which
+    spans every visible device), with its check that the env batch divides;
+    None on one device, where ``rl_train`` builds no mesh."""
+    if len(devices) == 1:
+        return None
+    from repro.launch.mesh import make_data_mesh
 
-    return jax.device_put(tree, jax.devices()[0])
+    if num_envs % len(devices):
+        raise ValueError(
+            f"num_envs {num_envs} is not divisible by the {len(devices)} devices: "
+            "the env batch shards evenly over all of them"
+        )
+    mesh = make_data_mesh()  # orders the devices by their place in the chips' topology
+    if set(mesh.devices.flat) != set(devices):
+        raise ValueError(f"make_data_mesh spans {list(mesh.devices.flat)}, the cell takes {devices}")
+    return mesh
+
+
+def shard_leading(mesh):
+    """A ``with_sharding_constraint`` of each leaf's leading axis onto the
+    mesh's ``data`` axis (replicated where it does not divide), from
+    ``jax.sharding`` alone: the reference's copy of the program's sharding."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    n = mesh.shape["data"]
+
+    def one(x):
+        spec = PartitionSpec("data") if x.ndim and x.shape[0] % n == 0 else PartitionSpec()
+        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+    return lambda tree: jax.tree_util.tree_map(one, tree)
+
+
+def undivided(hlo_text: str, num_envs: int, chips: int, n_scen: int, steps: int, obs_dim: int, n_evse: int) -> list[str]:
+    """Parts of the env batch that a partitioned program holds whole on a
+    chip, read from its per-chip shapes: the observations carried between
+    steps (``[envs, obs]``), the rollout's stacked observations (``[steps,
+    envs, obs]``) and the env state's per-port arrays (``[scenarios, envs
+    per scenario, ports, ...]``).  Empty where each chip holds
+    ``num_envs / chips`` envs of each."""
+    shapes = {tuple(map(int, d.split(","))) for d in re.findall(r"\[(\d+(?:,\d+)+)\]", hlo_text)}
+    rows = num_envs // chips
+    out = []
+    if (num_envs, obs_dim) in shapes or (rows, obs_dim) not in shapes:
+        out.append(f"observations: not [{rows},{obs_dim}] alone")
+    if (steps, rows, obs_dim) not in shapes:
+        out.append(f"rollout buffer: no [{steps},{rows},{obs_dim}]")
+    ports = [s for s in shapes if len(s) > 2 and s[2] == n_evse]
+    whole = (n_scen, num_envs // n_scen)
+    if any(s[:2] == whole for s in ports) or not any(s[0] * s[1] == rows for s in ports):
+        out.append(f"env state: not {rows} envs' [..., {n_evse}, ...] port arrays alone")
+    return out
 
 
 class Driver:
     """Shared part: configuration, tables and their fingerprint check."""
 
-    def __init__(self, config: dict, traffic: dict, seed: int, recorded_tables: dict | None):
+    def __init__(self, config: dict, traffic: dict, seed: int, recorded_tables: dict | None, devices=None):
+        import jax
+
         self.config, self.traffic, self.seed = config, traffic, seed
+        self.devices = list(devices or jax.devices()[:1])
+        self.mesh = None
         self.env, self.params = tables.build(config)
         self.tables = tables.as_dict(self.params)
         self.table_faults = tables.check(self.tables, recorded_tables) if recorded_tables is not None else []
@@ -50,12 +112,25 @@ class Driver:
         self.kept: dict[int, object] = {}
         self.finite: list = []
 
+    def _put(self, tree):
+        """``tree`` on the first device, or replicated over the mesh."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(tree, NamedSharding(self.mesh, PartitionSpec()) if self.mesh else self.devices[0])
+
+    def _on_mesh(self):
+        import jax
+
+        return jax.sharding.set_mesh(self.mesh) if self.mesh else contextlib.nullcontext()
+
     def _compile(self, *args):
         t0 = time.perf_counter()
         self._compiled = self._fn.lower(*args).compile()
         self.compile_s = time.perf_counter() - t0
         t0 = time.perf_counter()
         self.call(0)
+        self.finish()
         self.warmup_s = time.perf_counter() - t0
 
     def memory_text(self) -> str:
@@ -78,8 +153,13 @@ class Driver:
     def table_numbers(self) -> dict:
         return {"tables_changed": float(len(self.table_faults))}
 
+    def finish(self):
+        """Wait for every call made so far (a call that returns before its
+        result is ready leaves it to this)."""
+
     def reseed(self, seed: int):
         """Start over with another seed on the same compiled program."""
+        self.finish()
         self.seed, self.key, self.kept, self.finite = seed, stream(seed, "calls"), {}, []
 
 
@@ -87,10 +167,11 @@ class PPOUpdate(Driver):
     """One PPO update per call through ``repro.rl.make_train`` (fresh weights
     and fresh envs each call: ``make_train`` has no steady-state entry)."""
 
-    def __init__(self, config, traffic, seed, recorded_tables=None):
-        super().__init__(config, traffic, seed, recorded_tables)
+    def __init__(self, config, traffic, seed, recorded_tables=None, devices=None):
+        super().__init__(config, traffic, seed, recorded_tables, devices)
         import jax
 
+        from repro.distributed import env_sharding
         from repro.rl import PPOConfig, make_train
 
         n, ppo = config["num_envs"], dict(config["ppo"])
@@ -98,7 +179,9 @@ class PPOUpdate(Driver):
         self.env_steps_per_call = n * ppo["rollout_steps"]
         self.flops_per_call = counts.ppo_update_flops(config, self.shapes)
         cfg = PPOConfig(total_timesteps=self.env_steps_per_call, num_envs=n, **ppo)
-        train = make_train(cfg, self.env, scenario_params=_first_device_put(self.params))
+        self.mesh = data_mesh(self.devices, n)
+        shard_envs = env_sharding.make_shard_envs(self.mesh) if self.mesh else None
+        train = make_train(cfg, self.env, shard_envs=shard_envs, scenario_params=self._put(self.params))
 
         def call(key, i):
             out = train(jax.random.fold_in(key, i))
@@ -108,9 +191,19 @@ class PPOUpdate(Driver):
         self.key = stream(seed, "calls")
         self._fn = jax.jit(call)
         self._compiled = None
+        self._ref_updates = {}
 
     def setup(self):
-        self._compile(self.key, np.int32(0))
+        with self._on_mesh():
+            self._compile(self.key, np.int32(0))
+        if self.mesh:
+            ppo = self.config["ppo"]
+            whole = undivided(
+                self.hlo_text(), self.config["num_envs"], len(self.devices), len(self.config["scenarios"]),
+                ppo["rollout_steps"], self.shapes["obs_dim"], self.shapes["n_evse"],
+            )
+            if whole:
+                raise AssertionError(f"the timed program does not divide the env batch over {len(self.devices)} chips: {whole}")
 
     def call(self, i: int) -> int:
         import jax
@@ -137,9 +230,18 @@ class PPOUpdate(Driver):
 
         from bench.reference import ppo_ref
 
-        tabs = _first_device_put({k: jnp.asarray(v) for k, v in self.tables.items()})
-        update = jax.jit(ppo_ref.make_update(tabs, self.config, mlp_dtype or jnp.float32))
-        return lambda i: jax.device_get(update(jax.random.fold_in(self.key, i)))
+        dtype = mlp_dtype or jnp.float32
+        if dtype not in self._ref_updates:  # one compile per precision across reseeds
+            tabs = self._put({k: jnp.asarray(v) for k, v in self.tables.items()})
+            shard = shard_leading(self.mesh) if self.mesh else None
+            self._ref_updates[dtype] = jax.jit(ppo_ref.make_update(tabs, self.config, dtype, shard=shard))
+        update, key = self._ref_updates[dtype], self.key
+
+        def ref_of(i):
+            with self._on_mesh():
+                return jax.device_get(update(jax.random.fold_in(key, i)))
+
+        return ref_of
 
     @staticmethod
     def as_output(r) -> dict:
@@ -172,10 +274,15 @@ class RandomDay(Driver):
     """Batched simulation under uniform random actions: each call is one
     jitted day (``episode_steps`` steps) of ``AutoReset(VmapWrapper(env))``
     whose state carries over, returning per-env day sums of reward, energy
-    delivered, cars arrived and cars rejected."""
+    delivered, cars arrived and cars rejected.
 
-    def __init__(self, config, traffic, seed, recorded_tables=None):
-        super().__init__(config, traffic, seed, recorded_tables)
+    A call dispatches its day and then waits for the day before, so one day
+    is always queued on the chip, as in a loop that reads no result between
+    days: the chip does not sit idle while the host dispatches the next
+    day, and a host stall shorter than a day costs nothing."""
+
+    def __init__(self, config, traffic, seed, recorded_tables=None, devices=None):
+        super().__init__(config, traffic, seed, recorded_tables, devices)
         import jax
         import jax.numpy as jnp
 
@@ -186,7 +293,7 @@ class RandomDay(Driver):
         self.env_steps_per_call = n * self.steps
         self.flops_per_call = 0
         venv = AutoReset(VmapWrapper(self.env, n, num_scenarios=n_scen))
-        params = _first_device_put(self.params)
+        params = self._put(self.params)
 
         def call(state, key, i):
             def body(carry, k):
@@ -207,6 +314,7 @@ class RandomDay(Driver):
         self._fn = jax.jit(call, donate_argnums=0)
         self._compiled = None
         self.state = None
+        self._pending = None
 
     def reseed(self, seed: int):
         super().reseed(seed)
@@ -217,18 +325,25 @@ class RandomDay(Driver):
         self._compile(self.state, self.key, np.int32(0))
 
     def call(self, i: int) -> int:
-        import jax
-
         self.state, out = self._compiled(self.state, self.key, np.int32(i))
-        jax.block_until_ready(out)
         if i < N_CHECK:
             self.kept[i] = out
         self.finite.append(out["sums"])
+        self.finish()
+        self._pending = out
         return self.env_steps_per_call
+
+    def finish(self):
+        import jax
+
+        if self._pending is not None:
+            jax.block_until_ready(self._pending)
+            self._pending = None
 
     def release(self):
         import jax
 
+        self.finish()
         self.kept = jax.device_get(self.kept)
         self.finite = [np.asarray(x).sum() for x in jax.device_get(self.finite)]
         self.state = self._compiled = self._fn = None
@@ -253,7 +368,7 @@ class RandomDay(Driver):
         ftype = ftype or jnp.float32
         cfg, n = self.config, self.config["num_envs"]
         rows = jnp.asarray(self.rows())
-        tabs = _first_device_put(ref.cast_tables({k: jnp.asarray(v) for k, v in self.tables.items()}, ftype))
+        tabs = self._put(ref.cast_tables({k: jnp.asarray(v) for k, v in self.tables.items()}, ftype))
         n_heads, n_levels = self.shapes["n_heads"], self.shapes["n_levels"]
 
         def day(states, key):

@@ -1,6 +1,7 @@
 """Faults planted under the timed path, to show that ``correct`` catches
 them: a step that leaves its state unchanged, half of the batch left out
-(the mean taken over the rest), and an answer altered where it is made.
+(the mean taken over the rest), an answer altered where it is made, and,
+on several chips, the exchange between chips left out.
 
 Each is a context manager that patches the system's modules (never the
 reference's), so a driver built inside it runs the broken program.
@@ -90,4 +91,32 @@ def altered_answer(driver: str):
     return _env_step_patch(lambda state, ts: ts._replace(reward=ts.reward + REWARD_NUDGE))
 
 
-FAULTS = {"unchanged_state": unchanged_state, "half_batch": half_batch, "altered_answer": altered_answer}
+def no_exchange(driver: str):
+    """Each chip takes the loss and gradient of its own rows of the
+    minibatch and never sums them with the other chips' (data-parallel PPO
+    over the mesh set by ``set_mesh``; a cell on one chip has no exchange)."""
+    if driver != "ppo_update":
+        raise ValueError(f"no exchange between chips to leave out in {driver!r}")
+    import jax
+    from jax.sharding import PartitionSpec
+
+    from repro.rl import ppo
+
+    def value_and_grad(fn, **kw):
+        both = jax.value_and_grad(fn, **kw)
+
+        def local(params, *batch):
+            specs = (PartitionSpec(),) + (PartitionSpec("data"),) * len(batch)
+            return jax.shard_map(both, in_specs=specs, out_specs=PartitionSpec(), check_vma=False)(params, *batch)
+
+        return local
+
+    return _patched(ppo, "jax", _Proxy(jax, value_and_grad=value_and_grad))
+
+
+FAULTS = {
+    "unchanged_state": unchanged_state,
+    "half_batch": half_batch,
+    "altered_answer": altered_answer,
+    "no_exchange": no_exchange,
+}

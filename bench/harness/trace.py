@@ -213,5 +213,5 @@ def in_scope(path: str, scope: str) -> bool:
 def layer_of(path: str) -> str:
     """Innermost benchmark-known scope of an op (``env/charge_cars``,
     ``wrap/AutoReset``, ``ppo/update``, ...) or ``other``."""
-    found = re.findall(r"(?:^|[/(])((?:env|wrap|ppo|eval)/[A-Za-z_]+)", path)
+    found = re.findall(r"(?:^|[/(])((?:env|wrap|ppo|eval)/[A-Za-z0-9_]+)", path)
     return found[-1] if found else "other"

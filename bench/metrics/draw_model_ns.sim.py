@@ -1,5 +1,7 @@
 """Device time under ``env/draw_model`` per env-step, in the simulation cells:
-the car-model draw of every port (``jax.random.choice`` over the fleet mix)."""
+the car-model draw of every port (the fleet mix's CDF computed once per
+env, then each port's uniform compared with it and the entries below
+counted)."""
 
 
 def read(ctx):

@@ -12,7 +12,9 @@ published definitions, with the key discipline of a PureJaxRL update:
 
 ``mlp_dtype`` is the type the network computes in: float32 (matmuls at the
 chip's default precision, as the configuration states) or bfloat16 for the
-lower-precision control.
+lower-precision control.  ``shard`` places the env batch where the program
+under test places it (the env state and observations after the reset, the
+observations after each step); the identity leaves it to the compiler.
 """
 from __future__ import annotations
 
@@ -80,9 +82,12 @@ def _global_norm(tree):
     return jnp.sqrt(sum(jnp.sum(jnp.square(x)) for x in jax.tree_util.tree_leaves(tree)))
 
 
-def make_update(tabs: dict, config: dict, mlp_dtype=jnp.float32):
+def make_update(tabs: dict, config: dict, mlp_dtype=jnp.float32, shard=None):
     """key -> UpdateResult of one PPO update from fresh weights and fresh
-    envs, over the configuration's stacked scenario tables ``tabs``."""
+    envs, over the configuration's stacked scenario tables ``tabs``;
+    ``shard`` (a pytree -> pytree callable, the identity by default) is
+    applied to the env batch where the program constrains it."""
+    shard = shard or (lambda tree: tree)
     env_cfg, ppo = config["env"], config["ppo"]
     n_envs = config["num_envs"]
     n_scen = jax.tree_util.tree_leaves(tabs)[0].shape[0]
@@ -115,8 +120,8 @@ def make_update(tabs: dict, config: dict, mlp_dtype=jnp.float32):
     def update(key):
         key, k_net, k_reset = jax.random.split(key, 3)
         net0 = init_net(k_net, obs_dim, n_heads, n_levels, tuple(ppo["hidden"]))
-        states = ref.batch_reset(k_reset, tabs, n_envs)
-        obs = flat(ref.batch_observe(states, tabs, env_cfg))
+        states = shard(ref.batch_reset(k_reset, tabs, n_envs))
+        obs = shard(flat(ref.batch_observe(states, tabs, env_cfg)))
 
         def roll(carry, _):
             states, obs, key = carry
@@ -125,7 +130,7 @@ def make_update(tabs: dict, config: dict, mlp_dtype=jnp.float32):
             act = jax.random.categorical(k_act, logits, axis=-1)
             lp = _log_prob(logits, act)
             states, r, done, info = ref.batch_autoreset_step(k_env, states, nest(act), tabs, env_cfg, n_envs)
-            nobs = flat(ref.batch_observe(states, tabs, env_cfg))
+            nobs = shard(flat(ref.batch_observe(states, tabs, env_cfg)))
             return (states, nobs, key), (flat(done), act, v, flat(r) * scale, lp, obs)
 
         (states, obs, key), (done, act, val, rew, lp, tobs) = jax.lax.scan(roll, (states, obs, key), None, T)

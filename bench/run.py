@@ -132,6 +132,7 @@ def timed_window(driver, seconds: float, first: int) -> tuple[int, int, float]:
             i += 1
             if time.perf_counter() - t0 >= seconds:
                 break
+        driver.finish()
         elapsed = time.perf_counter() - t0
     return i - first, steps, elapsed
 
@@ -153,6 +154,7 @@ def traced_window(driver, n_calls: int, first: int, log_dir: str, keep: str | No
                 for i in range(first, first + n_calls):
                     with jax.profiler.TraceAnnotation(trace.CALL_SPAN):
                         driver.call(i)
+                driver.finish()
         finally:
             jax.profiler.stop_trace()
     ops, spans = trace.load_xplane(trace.find_xplane(log_dir), trace.scope_map(driver.hlo_text()))
@@ -227,7 +229,7 @@ def run_cell(cell: Cell, args, devices) -> int:
         jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
         obs.enable_trace_annotations(True)
     t0 = time.perf_counter()
-    driver = DRIVERS[cell.traffic["driver"]](cell.config, cell.traffic, args.seed, cell.recorded_tables)
+    driver = DRIVERS[cell.traffic["driver"]](cell.config, cell.traffic, args.seed, cell.recorded_tables, devices)
     t1 = time.perf_counter()
     driver.setup()
     setup_s = time.perf_counter() - T_START
@@ -247,10 +249,15 @@ def run_cell(cell: Cell, args, devices) -> int:
     # the reference follows the first calls; make them where the window did not
     for i in range(first + n_calls, N_CHECK):
         driver.call(i)
+    driver.finish()
     peak_bytes = memory_peak(devices)
     driver.release()
     attempted, failed = driver.attempted_failed(first, first + n_calls)
 
+    if args.trace:
+        # the reference carries no scopes: let it share the untraced runs'
+        # cache entry instead of compiling again
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
     checks = {**driver.table_numbers(), **driver.numbers(driver.kept, driver.reference())}
     checks["failed_calls"] = float(failed)
     missing = sorted(set(checks) ^ set(cell.limits))

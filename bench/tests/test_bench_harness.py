@@ -181,6 +181,7 @@ def test_op_names_and_scopes():
     assert trace.in_scope("jit(call)/ppo/rollout/while/body/env/reward/add", "env/")
     assert not trace.in_scope("jit(call)/ppo/update/loss/add", "ppo/rollout")
     assert trace.layer_of("jit(c)/while/body/wrap/AutoReset/env/depart_arrive/x") == "env/depart_arrive"
+    assert trace.layer_of("jit(c)/env/draw_cars/vmap(env/draw_soc0)/jit(_gamma)/log") == "env/draw_soc0"
 
 
 def test_reducer_by_hand():

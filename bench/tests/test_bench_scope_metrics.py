@@ -1,6 +1,7 @@
-"""The readers of the metrics inside the env step and the PPO update, checked
-on hand-built device ops against hand-computed values, and on the recorded
-chip traces, whose ops carry no such scope.  No device is needed."""
+"""The readers of the metrics inside the env step and the PPO update, and of
+the collectives between chips, checked on hand-built device ops against
+hand-computed values, and on the recorded one-chip traces, whose ops carry
+no such scope and no collective.  No device is needed."""
 from __future__ import annotations
 
 import os
@@ -13,7 +14,7 @@ from bench import run
 from bench.harness import trace
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
-NEW = ("draw_model_ns.sim", "car_lookup_ns.sim", "price_window_ns.train", "ppo_update_mlp_ms", "ppo_shuffle_ms", "ppo_logprob_ms")
+NEW = ("draw_model_ns.sim", "price_window_ns.train", "ppo_update_mlp_ms", "ppo_shuffle_ms", "ppo_logprob_ms", "collective_ms")
 
 STEP = "jit(call)/while/body/wrap/AutoReset/wrap/VmapWrapper/vmap(vmap(env/depart_arrive))"
 ROLL = "jit(call)/while/body/ppo/rollout/while/body"
@@ -35,14 +36,16 @@ OPS = [
     (35, UPD + "/ppo/optim/mul"),
     (15, "jit(call)/while/body/ppo/update/while/body/ppo/shuffle/gather"),
 ]
+# (chip, duration ns, opcode): collectives on two chips, and a fusion that is none
+COLLECTIVE_OPS = [(0, 40, "all-reduce"), (1, 60, "all-reduce"), (0, 30, "all-gather"), (1, 500, "fusion")]
 N_CALLS, ENV_STEPS = 2, 10
 WANT = {
     "draw_model_ns.sim": (100 + 50) / ENV_STEPS,
-    "car_lookup_ns.sim": 70 / ENV_STEPS,
     "price_window_ns.train": 40 / ENV_STEPS,
     "ppo_update_mlp_ms": (25 + 60) / N_CALLS / 1e6,
     "ppo_shuffle_ms": 15 / N_CALLS / 1e6,
     "ppo_logprob_ms": (10 + 5) / N_CALLS / 1e6,
+    "collective_ms": (40 + 60 + 30) / 2 / N_CALLS / 1e6,  # averaged over the two chips
 }
 
 
@@ -50,23 +53,29 @@ def _reading(tr, n_calls: int, env_steps: int) -> run.Reading:
     return run.Reading(trace=tr, n_calls=n_calls, env_steps=env_steps, flops_per_call=0, env_step_bytes=1, env_step_ops=1, peak={})
 
 
-def _hand_built() -> run.Reading:
+def _hand_built(metric: str = "") -> run.Reading:
     ops, t = [], 0
-    for i, (dur, scope) in enumerate(OPS):
-        ops.append(trace.Op(t, dur, f"fusion.{i}", scope, 0, "fusion"))
-        t += dur
+    if metric == "collective_ms":
+        for i, (chip, dur, opcode) in enumerate(COLLECTIVE_OPS):
+            ops.append(trace.Op(t, dur, f"{opcode}.{i}", "jit(call)/while/body", chip, opcode))
+            t += dur
+    else:
+        for i, (dur, scope) in enumerate(OPS):
+            ops.append(trace.Op(t, dur, f"fusion.{i}", scope, 0, "fusion"))
+            t += dur
     return _reading(trace.TraceData(ops, [trace.Span(0, t, trace.WINDOW_SPAN)]), N_CALLS, ENV_STEPS)
 
 
 @pytest.mark.parametrize("metric", NEW)
 def test_reader_by_hand(metric):
-    assert run.load_reader(ROOT, metric)(_hand_built()) == pytest.approx(WANT[metric])
+    assert run.load_reader(ROOT, metric)(_hand_built(metric)) == pytest.approx(WANT[metric])
 
 
 def test_parts_fit_inside_their_layer_by_hand():
     ctx = _hand_built()
     read = {m: run.load_reader(ROOT, m)(ctx) for m in NEW + ("env_step_ns.sim", "ppo_update_ms")}
-    assert read["draw_model_ns.sim"] + read["car_lookup_ns.sim"] <= read["env_step_ns.sim"]
+    assert read["collective_ms"] is None
+    assert read["draw_model_ns.sim"] <= read["env_step_ns.sim"]
     assert read["ppo_update_mlp_ms"] + read["ppo_shuffle_ms"] <= read["ppo_update_ms"]
 
 
