@@ -90,6 +90,12 @@ class ChargaxEnv(Environment):
                     layout.battery, enabled=self.config.battery
                 ),
             )
+        if layout.battery.enabled and layout.battery_node is None:
+            raise ValueError(
+                f"architecture {self.config.architecture!r} has no constraint "
+                "holding every port to carry the station battery; use "
+                "EnvConfig(battery=False)"
+            )
         if self.config.pad_evse or self.config.pad_nodes:
             layout = station.pad_layout(
                 layout,
@@ -136,10 +142,11 @@ class ChargaxEnv(Environment):
         # lognormal: E[X] = exp(mu + sigma^2/2) -> mu = log(mean) - sigma^2/2
         stay_mu_log = float(np.log(stay_mean) - 0.5 * stay_sigma**2)
 
-        # battery column participates in the root constraint only
+        # the battery column counts against the layout's battery node only
+        # (a tree's root)
         batt_col = np.zeros((lay.n_nodes, 1), dtype=np.float32)
         if lay.battery.enabled:
-            batt_col[0, 0] = 1.0
+            batt_col[lay.battery_node, 0] = 1.0
         member = np.concatenate([lay.member, batt_col], axis=1)
 
         b = lay.battery

@@ -1,13 +1,18 @@
 """Charging-station electrical architecture (paper §4 "EV Station Layout", Fig. 3).
 
-The station is a tree: the root is the grid connection, internal nodes are
-splitter/transformer/cable assemblies with a maximum current ``I_H`` and an
-efficiency ``eta_H``, and leaves are EVSEs (charging ports).
+A station is a set of EVSEs (charging ports) under named current constraints.
+Each constraint has a maximum current ``I_H``, an efficiency ``eta_H`` and the
+ports whose current it carries.  In the paper's layouts the constraints form
+a tree: the root is the grid connection, internal nodes are
+splitter/transformer/cable assemblies, and leaves are EVSEs
+(:func:`flatten_tree`).  A site wired across the lines of a three-phase
+transformer is no tree: a port between two lines counts against both, and
+:func:`constraint_network` builds it from constraints whose ports overlap.
 
-TPU adaptation (DESIGN.md §3): the pointer tree is flattened at construction
+TPU adaptation (DESIGN.md §3): either form is flattened at construction
 time into dense arrays —
 
-  * ``member``       (n_nodes, n_evse) 0/1 — leaf j lies in the subtree of node i
+  * ``member``       (n_nodes, n_evse) 0/1 — port j counts against constraint i
   * ``node_limit``   (n_nodes,)  max current I_H [A]
   * ``node_eff``     (n_nodes,)  efficiency eta_H in (0, 1]
   * per-EVSE vectors (voltage, I_max, efficiency, is_dc)
@@ -64,6 +69,23 @@ class Node:
 
 
 @dataclasses.dataclass
+class Constraint:
+    """A named current limit over a set of ports: one row of ``member``.
+
+    ``equipment`` names the device the power passes through; constraints
+    that share it (the lines of one transformer) count its efficiency once
+    in a port's path efficiency.  ``None`` means the constraint is a device
+    of its own.
+    """
+
+    name: str
+    max_current: float
+    ports: Sequence[int]
+    efficiency: float = 1.0
+    equipment: str | None = None
+
+
+@dataclasses.dataclass
 class BatteryConfig:
     """Optional station battery (modelled like an EVSE; paper §4)."""
 
@@ -88,9 +110,13 @@ class StationLayout:
     evse_voltage: np.ndarray  # (n_evse,) effective volts
     evse_max_current: np.ndarray  # (n_evse,) amps
     evse_eff: np.ndarray  # (n_evse,) port efficiency
-    evse_path_eff: np.ndarray  # (n_evse,) product of efficiencies root->leaf
+    evse_path_eff: np.ndarray  # (n_evse,) product of efficiencies grid->port
     evse_is_dc: np.ndarray  # (n_evse,) float32 0/1
     battery: BatteryConfig
+    # row of ``member`` that carries the station battery's current (a tree's
+    # root); ``None`` where no constraint holds every port, so a battery
+    # has nowhere to connect
+    battery_node: int | None
     # 0/1 per EVSE: 0 marks a padding lane added by :func:`pad_layout` so
     # heterogeneous stations can share one array shape (FleetEnv).  ``None``
     # means "all real" (the common single-station case).
@@ -108,50 +134,80 @@ class StationLayout:
         return self.evse_mask
 
 
-def flatten_tree(root: Node, battery: BatteryConfig | None = None) -> StationLayout:
-    """Flatten a station tree into the dense arrays used by the simulator."""
-    leaves: list[EVSE] = []
-    nodes: list[Node] = []
-    # (node_index, leaf_indices) accumulated during DFS
-    node_members: list[list[int]] = []
-    leaf_path_eff: list[float] = []
+def constraint_network(
+    ports: Sequence[EVSE],
+    constraints: Sequence[Constraint],
+    battery: BatteryConfig | None = None,
+    battery_node: str | None = None,
+) -> StationLayout:
+    """Dense arrays of a station whose constraints may overlap.
 
-    def dfs(n: Node | EVSE, path_eff: float) -> list[int]:
-        if isinstance(n, EVSE):
-            leaves.append(n)
-            leaf_path_eff.append(path_eff * n.efficiency)
-            return [len(leaves) - 1]
-        nodes.append(n)
-        my_idx = len(nodes) - 1
-        node_members.append([])  # placeholder, filled after children
-        mine: list[int] = []
-        for c in n.children:
-            mine.extend(dfs(c, path_eff * n.efficiency))
-        node_members[my_idx] = mine
-        return mine
-
-    dfs(root, 1.0)
-    n_evse, n_nodes = len(leaves), len(nodes)
+    A port's path efficiency is its own times that of each piece of
+    equipment holding it, in constraint order.  ``battery_node`` names the
+    constraint whose row carries the station battery, if it has one.
+    """
+    n_evse, n_nodes = len(ports), len(constraints)
     if n_evse == 0:
-        raise ValueError("station tree has no EVSE leaves")
-
+        raise ValueError("station has no EVSEs")
+    names = [c.name for c in constraints]
+    if len(set(names)) != n_nodes:
+        raise ValueError(f"constraint names repeat: {names}")
     member = np.zeros((n_nodes, n_evse), dtype=np.float32)
-    for i, mem in enumerate(node_members):
-        member[i, mem] = 1.0
+    equipment_eff: dict[str, float] = {}
+    for i, c in enumerate(constraints):
+        held = list(c.ports)
+        if any(not 0 <= j < n_evse for j in held):
+            raise ValueError(f"constraint {c.name!r} holds a port outside 0..{n_evse - 1}")
+        member[i, held] = 1.0
+        eq = c.equipment or c.name
+        if equipment_eff.setdefault(eq, c.efficiency) != c.efficiency:
+            raise ValueError(f"constraints of equipment {eq!r} give it two efficiencies")
+
+    path_eff = []
+    for j, port in enumerate(ports):
+        eff, passed = 1.0, set()
+        for i, c in enumerate(constraints):
+            eq = c.equipment or c.name
+            if member[i, j] and eq not in passed:
+                passed.add(eq)
+                eff *= c.efficiency
+        path_eff.append(eff * port.efficiency)
 
     return StationLayout(
         n_evse=n_evse,
         n_nodes=n_nodes,
         member=member,
-        node_limit=np.array([n.max_current for n in nodes], dtype=np.float32),
-        node_eff=np.array([n.efficiency for n in nodes], dtype=np.float32),
-        evse_voltage=np.array([l.voltage for l in leaves], dtype=np.float32),
-        evse_max_current=np.array([l.max_current for l in leaves], dtype=np.float32),
-        evse_eff=np.array([l.efficiency for l in leaves], dtype=np.float32),
-        evse_path_eff=np.array(leaf_path_eff, dtype=np.float32),
-        evse_is_dc=np.array([float(l.is_dc) for l in leaves], dtype=np.float32),
+        node_limit=np.array([c.max_current for c in constraints], dtype=np.float32),
+        node_eff=np.array([c.efficiency for c in constraints], dtype=np.float32),
+        evse_voltage=np.array([p.voltage for p in ports], dtype=np.float32),
+        evse_max_current=np.array([p.max_current for p in ports], dtype=np.float32),
+        evse_eff=np.array([p.efficiency for p in ports], dtype=np.float32),
+        evse_path_eff=np.array(path_eff, dtype=np.float32),
+        evse_is_dc=np.array([float(p.is_dc) for p in ports], dtype=np.float32),
         battery=battery or BatteryConfig(enabled=False),
+        battery_node=None if battery_node is None else names.index(battery_node),
     )
+
+
+def flatten_tree(root: Node, battery: BatteryConfig | None = None) -> StationLayout:
+    """Flatten a station tree into the dense arrays used by the simulator:
+    each node is a constraint over the leaves of its subtree, in DFS
+    order, and the root carries the battery."""
+    leaves: list[EVSE] = []
+    constraints: list[Constraint] = []
+
+    def dfs(n: Node | EVSE) -> list[int]:
+        if isinstance(n, EVSE):
+            leaves.append(n)
+            return [len(leaves) - 1]
+        c = Constraint(f"node{len(constraints)}", n.max_current, [], n.efficiency)
+        constraints.append(c)
+        for child in n.children:
+            c.ports.extend(dfs(child))
+        return c.ports
+
+    dfs(root)
+    return constraint_network(leaves, constraints, battery, battery_node="node0")
 
 
 # ---------------------------------------------------------------------------
@@ -278,6 +334,63 @@ def deep_split(
     return flatten_tree(root, battery)
 
 
+# Caltech ACN: the workplace garage of ACN-Data (Lee, Li, Low, e-Energy
+# 2019), as ACN-Sim's ``caltech_acn`` site models it.
+CALTECH_N_EVSE = 54
+CALTECH_POD_SIZE = 8
+CALTECH_LINE_VOLTAGE = 208.0  # 208Y/120 V secondary; each EVSE is wired line to line
+CALTECH_EVSE_CURRENT = 32.0  # Level 2: 208 V x 32 A = 6.66 kW
+CALTECH_TRANSFORMER_VA = 150e3
+CALTECH_POD_CURRENT = 80.0
+CALTECH_PHASE_PAIRS = ("AB", "BC", "CA")
+
+
+def caltech_acn(battery: BatteryConfig | None = None) -> StationLayout:
+    """The Caltech ACN garage: 54 Level-2 EVSEs under a 150 kVA transformer's
+    three secondary lines, and two pods of 8 EVSEs capped at 80 A each.
+
+    Constraint rows: lines A, B and C (``150 kVA / (sqrt(3) 208 V)`` =
+    416.4 A each), then the ClipperCreek and AeroVironment pods.  Port ``j``
+    is wired across the line pair ``CALTECH_PHASE_PAIRS[j % 3]``, so the
+    pairs hold 18 ports each and each pod is split over the three pairs
+    (assumed: the per-port phase map is not published with the site's
+    sizes).  Efficiencies (transformer 0.98, port 0.95) are assumed as in
+    the other builders.
+
+    Eq. 5 reading: a port counts ``|I|`` against each of the two lines it
+    spans.  ``acnportal`` bounds the magnitude of each line's phasor
+    current instead, e.g. ``|I_AB - I_CA|`` on line A; since that is at most
+    ``|I_AB| + |I_CA|``, this reading is the conservative one and departs
+    from ``acnportal``'s by never admitting the phasor cancellation.  The
+    transformer's primary-side constraints are left out: under the
+    magnitude reading they restate the secondary lines through the turns
+    ratio.  No constraint holds every port, so the site has no root on
+    which a station battery could sit (``battery_node`` is ``None``).
+    """
+    n, k = CALTECH_N_EVSE, CALTECH_POD_SIZE
+    ports = [
+        EVSE(CALTECH_LINE_VOLTAGE, CALTECH_EVSE_CURRENT, efficiency=0.95, is_dc=False)
+        for _ in range(n)
+    ]
+    pair = [CALTECH_PHASE_PAIRS[j % 3] for j in range(n)]
+    line_limit = CALTECH_TRANSFORMER_VA / (np.sqrt(3.0) * CALTECH_LINE_VOLTAGE)
+    lines = [
+        Constraint(
+            f"line_{x}",
+            line_limit,
+            [j for j in range(n) if x in pair[j]],
+            efficiency=0.98,
+            equipment="transformer",
+        )
+        for x in "ABC"
+    ]
+    pods = [
+        Constraint(name, CALTECH_POD_CURRENT, range(i * k, (i + 1) * k))
+        for i, name in enumerate(("pod_clippercreek", "pod_aerovironment"))
+    ]
+    return constraint_network(ports, lines + pods, battery)
+
+
 ARCHITECTURES = {
     "single_ac_16": lambda **kw: single_charger_type(16, dc=False, **kw),
     "single_dc_16": lambda **kw: single_charger_type(16, dc=True, **kw),
@@ -288,4 +401,6 @@ ARCHITECTURES = {
     "single_dc_8": lambda **kw: single_charger_type(8, dc=True, **kw),
     "kiosk_ac_4": lambda **kw: single_charger_type(4, dc=False, **kw),
     "deep_2x4": lambda **kw: deep_split(2, 4, **kw),
+    # a network, not a tree: 54 ports over three transformer lines and two pods
+    "caltech_acn54": caltech_acn,
 }

@@ -8,7 +8,7 @@ The step is a sequence of individually-jittable pure stages::
   decode        — map the discrete factorized action to target amps
                   (direct and the paper's additive/delta form),
   request       — clip targets by car curve, port limits and pack headroom,
-                  then enforce the Eq. 5 tree constraints (``apply_actions``),
+                  then enforce the Eq. 5 constraints (``apply_actions``),
   allocate      — curtail the station's *grid-side* charging power against
                   the feeder/transformer envelope (``grid_cap_kw_table``);
                   with the default unlimited cap this stage is an exact
@@ -258,13 +258,16 @@ def constraint_scale(
     member: jnp.ndarray,  # (n_nodes, n_leaves)
     node_budget: jnp.ndarray,  # (n_nodes,) eta_H * I_H
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-leaf multiplicative scale enforcing Eq. 5 on every subtree.
+    """Per-leaf multiplicative scale enforcing Eq. 5 on every constraint.
 
-    We use the conservative cable-thermal reading of Eq. 5 — each node carries
-    the sum of *magnitudes* of its subtree currents (DESIGN.md §7).  With
-    ``scale_j = min_{H ∋ j} s_H`` and ``s_H = budget_H / load_H`` the invariant
-    ``sum_j |I_j * scale_j| <= budget_H`` holds for every node H, which the
-    hypothesis tests assert.
+    We use the conservative cable-thermal reading of Eq. 5 — each constraint
+    carries the sum of *magnitudes* of its members' currents (DESIGN.md §7).
+    With ``scale_j = min_{H ∋ j} s_H`` and ``s_H = budget_H / load_H`` the
+    invariant ``sum_j |I_j * scale_j| <= budget_H`` holds for every
+    constraint H and any 0/1 ``member``: a tree's subtrees, or a network
+    whose rows overlap (a port wired across two lines counts on both).
+    The hypothesis tests assert it on trees, ``test_station_network`` on a
+    network.
 
     Returns (per-leaf scale in (0, 1], max pre-rescale node excess in amps).
     """
@@ -275,7 +278,8 @@ def constraint_scale(
     )  # (n_nodes,)
     s_node = jnp.minimum(1.0, node_budget / jnp.maximum(load, 1e-9))
     excess = jnp.max(jnp.maximum(load - node_budget, 0.0))
-    # min over ancestors; a leaf with no constrained ancestor is unscaled
+    # min over the constraints holding each leaf; a leaf that none holds is
+    # unscaled
     per_leaf = jnp.where(member > 0, s_node[:, None], jnp.inf)
     scale = jnp.min(per_leaf, axis=0)
     return jnp.where(jnp.isfinite(scale), scale, 1.0), excess
@@ -316,10 +320,11 @@ def apply_actions(
     )
     i_batt = pole_clip(target_batt, b_up, b_down, 1.0)
 
-    # --- Eq. 5 tree constraints (battery = extra leaf on the root) ----------
-    leaf_currents = jnp.concatenate([i_evse, i_batt[None]])
-    scale, excess = constraint_scale(leaf_currents, params.member, params.node_budget)
-    leaf_currents = leaf_currents * scale
+    # --- Eq. 5 constraints (battery = extra leaf on the battery node) -------
+    with annotate("env/constraint_scale"):
+        leaf_currents = jnp.concatenate([i_evse, i_batt[None]])
+        scale, excess = constraint_scale(leaf_currents, params.member, params.node_budget)
+        leaf_currents = leaf_currents * scale
     return AppliedActions(leaf_currents[:-1], leaf_currents[-1], excess)
 
 

@@ -29,6 +29,16 @@ import jax.numpy as jnp
 import numpy as np
 
 
+# KPIs that a step's ``info`` implies but does not carry: name -> the
+# per-step value computed from ``info``, only where an accumulator tracks it
+DERIVED_KPIS = {
+    # 1 in an env-step in which Eq. 5 scaled some current (a constraint's
+    # load exceeded its budget before the rescale): its per-step mean is the
+    # share of env-steps in which some constraint bound
+    "constraint_binds": lambda info: (info["constraint_excess"] > 0.0).astype(jnp.float32),
+}
+
+
 class MetricsAccumulator(NamedTuple):
     """Named scalar sums/maxes as a pytree (dict leaves are jit/vmap/scan
     compatible; the key sets are static structure)."""
@@ -66,10 +76,13 @@ class MetricsAccumulator(NamedTuple):
     def update(self, values: dict[str, Any]) -> "MetricsAccumulator":
         """One step's named scalars folded in: sums add, maxes max-merge.
 
-        Every tracked name must be present in ``values`` (missing keys are a
-        trace-time ``KeyError`` — silently skipping a KPI would report a
-        wrong total); extra keys are ignored.
+        Every tracked name must be present in ``values`` or be one of
+        ``DERIVED_KPIS`` (missing keys are a trace-time ``KeyError`` —
+        silently skipping a KPI would report a wrong total); extra keys are
+        ignored.
         """
+        derived = [n for n in self.names if n in DERIVED_KPIS and n not in values]
+        values = {**values, **{n: DERIVED_KPIS[n](values) for n in derived}}
         sums = {n: s + values[n] for n, s in self.sums.items()}
         maxes = {n: jnp.maximum(m, values[n]) for n, m in self.maxes.items()}
         return MetricsAccumulator(sums, maxes, self.count + 1.0)

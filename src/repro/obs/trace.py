@@ -23,37 +23,38 @@ reader that reads the scope; ``env/*`` and ``wrap/*`` are also read as
 wholes (``env_step_ns.*``, ``env_step_roofline.sim``: all of ``env/``;
 ``wrap_self_ns.sim``: ``wrap/`` outside ``env/``).
 
-=========================  ==========================================  ========================
-``env/decode``             action decoding (direct / delta modes)      ``env/`` only
-``env/apply_actions``      request: Eq. 5 constrained currents         ``env/`` only
-``env/allocate``           feeder-cap curtailment                      ``env/`` only
-``env/fused_transition``   fused kernel route (request..deliver)       none: no cell runs it
-``env/charge_cars``        deliver: energy integration + V2G debt      ``env/`` only
-``env/depart_arrive``      departures, arrivals, rejections            ``env/`` only
-  ``env/departures``       departure masks and state clears            ``env/`` only
-  ``env/arrive_count``     rate lookup, Poisson draw, FCFS rank        ``env/`` only
-  ``env/draw_cars``        every port's profile draws                  ``env/`` only
-    ``env/draw_model``     compare-and-count over the fleet mix's CDF  ``draw_model_ns.sim``
-    ``env/draw_soc0``      arrival SoC draw (``random.beta``)          ``env/`` only
-  ``env/car_lookup``       per-port selects over the model rows        ``car_lookup_ns.sim``
-  ``env/place_cars``       user profiles and the state writes          ``env/`` only
-``env/reward``             settle: Eq. 1-3 reward, grid penalties      ``env/`` only
-``env/observe``            the step's observation build                ``env/`` only
-  ``env/price_window``     price-horizon gather                        ``price_window_ns.train``
-``wrap/<Wrapper>``         each wrapper layer's step (Vmap, AutoReset  ``wrap_self_ns.sim``
-                           reset and select, Log, FleetAdapter)
-``ppo/rollout``            the rollout scan                            ``ppo_rollout_ms``
-``ppo/gae``                advantage estimation                        none
-``ppo/update``             minibatch epochs                            ``ppo_update_ms``
-  ``ppo/shuffle``          permutation, gather, minibatch reshapes     ``ppo_shuffle_ms``
-  ``ppo/optim``            AdamW update and apply                      none
-``ppo/mlp``                actor-critic forward; backward ops read     ``ppo_update_mlp_ms``
-                           ``transpose(jvp(ppo/mlp))``                 (ops also in ``ppo/update``)
-``ppo/logprob``            factorised categorical log-probability      ``ppo_logprob_ms``
-``eval/rollout``           evaluation episodes                         none
-``eval/serve``             batched policy inference step               none
-``profile/*``              ``rl_train --profile`` host windows         none
-=========================  ==========================================  ========================
+===========================  ==========================================  ========================
+``env/decode``               action decoding (direct / delta modes)      ``env/`` only
+``env/apply_actions``        request: port clips, then Eq. 5             ``env/`` only
+  ``env/constraint_scale``   Eq. 5 loads, per-port min, rescale          ``constraint_scale_ns.train``
+``env/allocate``             feeder-cap curtailment                      ``env/`` only
+``env/fused_transition``     fused kernel route (request..deliver)       none: no cell runs it
+``env/charge_cars``          deliver: energy integration + V2G debt      ``env/`` only
+``env/depart_arrive``        departures, arrivals, rejections            ``env/`` only
+  ``env/departures``         departure masks and state clears            ``env/`` only
+  ``env/arrive_count``       rate lookup, Poisson draw, FCFS rank        ``env/`` only
+  ``env/draw_cars``          every port's profile draws                  ``env/`` only
+    ``env/draw_model``       compare-and-count over the fleet mix's CDF  ``draw_model_ns.sim``
+    ``env/draw_soc0``        arrival SoC draw (``random.beta``)          ``env/`` only
+  ``env/car_lookup``         per-port selects over the model rows        none
+  ``env/place_cars``         user profiles and the state writes          ``env/`` only
+``env/reward``               settle: Eq. 1-3 reward, grid penalties      ``env/`` only
+``env/observe``              the step's observation build                ``env/`` only
+  ``env/price_window``       price-horizon gather                        ``price_window_ns.train``
+``wrap/<Wrapper>``           each wrapper layer's step (Vmap, AutoReset  ``wrap_self_ns.sim``
+                             reset and select, Log, FleetAdapter)
+``ppo/rollout``              the rollout scan                            ``ppo_rollout_ms``
+``ppo/gae``                  advantage estimation                        none
+``ppo/update``               minibatch epochs                            ``ppo_update_ms``
+  ``ppo/shuffle``            permutation, gather, minibatch reshapes     ``ppo_shuffle_ms``
+  ``ppo/optim``              AdamW update and apply                      none
+``ppo/mlp``                  actor-critic forward; backward ops read     ``ppo_update_mlp_ms``
+                             ``transpose(jvp(ppo/mlp))``                 (ops also in ``ppo/update``)
+``ppo/logprob``              factorised categorical log-probability      ``ppo_logprob_ms``
+``eval/rollout``             evaluation episodes                         none
+``eval/serve``               batched policy inference step               none
+``profile/*``                ``rl_train --profile`` host windows         none
+===========================  ==========================================  ========================
 
 No name is a prefix of another: the benchmark's reducer matches scopes by
 prefix.  ``env/price_window`` is set by the step's observation only, so

@@ -3,9 +3,11 @@ boundaries: the scan path matches a host Python loop bit-for-bit."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import ChargaxEnv, EnvConfig
 from repro.envs import AutoReset, LogWrapper, VmapWrapper
+from repro.utils import replace
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -129,3 +131,31 @@ def test_metrics_default_off_keeps_state_lean():
         env.default_params,
     )
     assert ts.state.metrics is None
+
+
+def test_constraint_binds_is_the_share_of_steps_scaled_by_eq5():
+    """``constraint_binds`` is derived from ``info["constraint_excess"]``,
+    so its per-step mean is the share of env-steps in which some Eq. 5
+    constraint bound (here on the Caltech garage at its busiest hours)."""
+    env = ChargaxEnv(EnvConfig(architecture="caltech_acn54", battery=False, scenario="work"))
+    params = env.default_params
+    wenv = LogWrapper(AutoReset(VmapWrapper(env, N_ENVS)), metrics=("constraint_binds",))
+    _, state = wenv.reset(jax.random.key(0), params)
+    inner = replace(state.env_state, t=jnp.full((N_ENVS,), 8 * 12, jnp.int32))  # 08:00
+    state = state._replace(env_state=inner)
+    top = jnp.full((N_ENVS, env.num_action_heads), 2 * env.config.discretization, jnp.int32)
+
+    @jax.jit
+    def rollout(state):
+        def body(s, key):
+            ts = wenv.step(key, s, top, params)
+            return ts.state, ts.info["constraint_excess"] > 0.0
+
+        return jax.lax.scan(body, state, jax.random.split(jax.random.key(1), 36))
+
+    final, bound = rollout(state)
+    share = np.asarray(bound, np.float32).mean(axis=0)
+    got = np.asarray(final.metrics.sums["constraint_binds"]) / 36.0
+    np.testing.assert_allclose(got, share, rtol=1e-6)
+    assert 0.0 < share.mean() < 1.0
+    assert final.metrics.flush(means=("constraint_binds",))["constraint_binds_per_step"] == pytest.approx(float(share.mean()))

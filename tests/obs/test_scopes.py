@@ -15,6 +15,7 @@ jax.config.update("jax_platform_name", "cpu")
 
 # scope -> the existing scopes it sits inside (one of them must hold each op)
 PARENTS = {
+    "env/constraint_scale": ("env/apply_actions",),
     "env/departures": ("env/depart_arrive",),
     "env/arrive_count": ("env/depart_arrive",),
     "env/draw_cars": ("env/depart_arrive",),
