@@ -81,9 +81,17 @@ def sample_action(key: jax.Array, logits: jnp.ndarray) -> jnp.ndarray:
 
 
 def log_prob(logits: jnp.ndarray, action: jnp.ndarray) -> jnp.ndarray:
-    """Joint log-probability, summed over heads."""
+    """Joint log-probability, summed over heads.
+
+    Each head's level is picked by compare-and-select over the K levels, not
+    by ``take_along_axis``: on a TPU the gather that the latter lowers to,
+    over logits fresh from the output matmul, costs far more than the
+    O(K) select.  The sum of one selected value and K-1 exact zeros is that
+    value, so the result and its gradient are the gather's bit for bit.
+    """
     logp = jax.nn.log_softmax(logits, axis=-1)
-    picked = jnp.take_along_axis(logp, action[..., None], axis=-1)[..., 0]
+    hit = jnp.arange(logits.shape[-1], dtype=action.dtype) == action[..., None]
+    picked = jnp.where(hit, logp, 0.0).sum(axis=-1)
     return picked.sum(axis=-1)
 
 

@@ -53,6 +53,17 @@ def _spec(tree, sharding):
     )
 
 
+def _scoped_ops(text: str, *scopes: str) -> list[tuple[str, str]]:
+    """(opcode, op_name) of each compiled op whose op_name holds one of ``scopes``."""
+    found = []
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        op = re.search(r"= .*? ([a-z][a-z0-9-]*)\(", line)
+        if name and op and any(s in name.group(1) for s in scopes):
+            found.append((op.group(1), name.group(1)))
+    return found
+
+
 def _kernel_text(one_chip, block_envs: int) -> str:
     f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
     slabs = tuple(f32(N_ENVS, P) for _ in range(7))
@@ -99,12 +110,70 @@ def test_car_model_draw_and_lookup_compile_without_gathers_for_v5e(one_chip):
         text = jax.jit(venv.step).lower(*_spec((key, state, action, params), one_chip)).compile().as_text()
     finally:
         enable_trace_annotations(prev)
-    found = []
-    for line in text.splitlines():
-        name = re.search(r'op_name="([^"]*)"', line)
-        op = re.search(r"= .*? ([a-z][a-z0-9-]*)\(", line)
-        if name and op and ("env/draw_model" in name.group(1) or "env/car_lookup" in name.group(1)):
-            found.append((op.group(1), name.group(1)))
+    found = _scoped_ops(text, "env/draw_model", "env/car_lookup")
     assert any("env/car_lookup" in n for _, n in found)
     bad = [(op, n) for op, n in found if op in ("gather", "while")]
     assert [b for b in bad if not b[1].endswith("/env/draw_cars/env/draw_model/gather")] == []
+
+
+def _ppo_logprob_programs(one_chip, obs_dim: int, n_heads: int, rows: int) -> dict:
+    """The two programs that run ``networks.log_prob`` inside ``make_train``,
+    built as it builds them: the rollout's act step (forward, sample, log-prob)
+    at one chip's 4,096 envs, and the PPO loss gradient at one minibatch."""
+    from repro.obs import annotate
+    from repro.rl import networks
+
+    n_levels = 21
+    hidden = (128, 128)
+    params = jax.eval_shape(
+        lambda k: networks.init_actor_critic(k, obs_dim, n_heads, n_levels, hidden), jax.random.key(0)
+    )
+
+    def policy(p, obs):
+        with annotate("ppo/mlp"):
+            return networks.apply_actor_critic(p, obs, n_heads, n_levels)
+
+    def log_prob(logits, action):
+        with annotate("ppo/logprob"):
+            return networks.log_prob(logits, action)
+
+    def act(p, key, obs):
+        out = policy(p, obs)
+        action = networks.sample_action(key, out.logits)
+        return action, log_prob(out.logits, action), out.value
+
+    def loss(p, obs, action, old_logp, old_value, gae, targets):
+        out = policy(p, obs)
+        ratio = jnp.exp(log_prob(out.logits, action) - old_logp)
+        gae_n = (gae - gae.mean()) / (gae.std() + 1e-8)
+        pg = -jnp.minimum(ratio * gae_n, jnp.clip(ratio, 0.8, 1.2) * gae_n).mean()
+        v_clip = old_value + jnp.clip(out.value - old_value, -10.0, 10.0)
+        v = 0.5 * jnp.maximum(jnp.square(out.value - targets), jnp.square(v_clip - targets)).mean()
+        return pg + 0.25 * v - 0.01 * networks.entropy(out.logits).mean()
+
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    act_args = _spec((params, key, f32(N_ENVS, obs_dim)), one_chip)
+    loss_args = _spec((params, f32(rows, obs_dim), i32(rows, n_heads)) + (f32(rows),) * 4, one_chip)
+    prev = enable_trace_annotations(True)
+    try:
+        return {
+            "act": jax.jit(act).lower(*act_args).compile().as_text(),
+            "loss_grad": jax.jit(jax.grad(loss)).lower(*loss_args).compile().as_text(),
+        }
+    finally:
+        enable_trace_annotations(prev)
+
+
+@pytest.mark.parametrize(
+    "obs_dim,n_heads", [(137, 17), (441, 55)], ids=["paper16_17_heads", "caltech_55_heads"]
+)
+def test_ppo_log_prob_compiles_without_gathers_for_v5e(one_chip, obs_dim, n_heads):
+    """``networks.log_prob`` in the rollout's act step (4,096 envs) and in the
+    loss gradient of one minibatch (307,200 rows): the 21-level pick compiles
+    to selects, with no gather under ``ppo/logprob``."""
+    for program, text in _ppo_logprob_programs(one_chip, obs_dim, n_heads, 307_200).items():
+        scoped = _scoped_ops(text, "ppo/logprob")
+        assert scoped, f"{program}: no op under ppo/logprob"
+        assert [s for s in scoped if s[0] == "gather"] == [], program

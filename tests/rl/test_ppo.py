@@ -31,6 +31,29 @@ def test_factorized_logprob_and_entropy():
     np.testing.assert_allclose(ent, 2 * np.log(4), rtol=1e-5)
 
 
+@pytest.mark.parametrize("n_heads,n_levels", [(17, 21), (55, 21), (2, 4)])
+def test_log_prob_select_is_gather_bit_for_bit(n_heads, n_levels):
+    """``log_prob`` picks by compare-and-select; forward and gradient equal
+    the ``take_along_axis`` form bit for bit, actions 0 and K-1 included."""
+    k_logits, k_act, k_w = jax.random.split(jax.random.key(3), 3)
+    logits = 3.0 * jax.random.normal(k_logits, (4096, n_heads, n_levels))
+    action = jax.random.randint(k_act, (4096, n_heads), 0, n_levels)
+    action = action.at[0].set(0).at[1].set(n_levels - 1)
+    weight = jax.random.normal(k_w, (4096,))
+
+    def gather_form(lg):
+        logp = jax.nn.log_softmax(lg, axis=-1)
+        return jnp.take_along_axis(logp, action[..., None], axis=-1)[..., 0].sum(-1)
+
+    got = jax.jit(networks.log_prob)(logits, action)
+    want = jax.jit(gather_form)(logits)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    grad_got = jax.jit(jax.grad(lambda lg: (networks.log_prob(lg, action) * weight).sum()))(logits)
+    grad_want = jax.jit(jax.grad(lambda lg: (gather_form(lg) * weight).sum()))(logits)
+    np.testing.assert_array_equal(np.asarray(grad_got), np.asarray(grad_want))
+
+
 def test_orthogonal_init_is_orthogonal():
     w = networks.orthogonal(jax.random.key(2), (16, 16), scale=1.0)
     np.testing.assert_allclose(np.asarray(w @ w.T), np.eye(16), atol=1e-4)
